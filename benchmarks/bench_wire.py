@@ -116,6 +116,9 @@ def run(steps: int = 10, verbose: bool = False) -> list:
     """Spawn the 8-device measurement child and collect its rows."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    # the parent holds the accelerator, if there is one: the child measures
+    # on 8 virtual CPU devices and must not try to open it
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.pathsep.join(
         [str(REPO / "src"), str(REPO)]
         + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))

@@ -21,6 +21,8 @@ jax.config.update("jax_enable_x64", True)
 
 
 def main(argv=None):
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=None,
                     help="iteration count (default 800; 200 with --quick, "

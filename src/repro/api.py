@@ -50,6 +50,7 @@ CLI sanity gate::
 from __future__ import annotations
 
 import argparse
+import contextlib
 import dataclasses
 import json
 import pathlib
@@ -752,9 +753,9 @@ class Runner:
                                      a replicated tree).
     ``last_report``                — :class:`repro.obs.RunReport` from the
                                      most recent ``run()``: env stamp,
-                                     compute-vs-wire step-time breakdown,
-                                     and exact bits-on-wire (None until a
-                                     run completes).
+                                     measured step time, and exact
+                                     bits-on-wire (None until a run
+                                     completes).
     """
     spec: Optional[ExperimentSpec] = None
     last_report: Optional[obs.RunReport] = None
@@ -914,6 +915,14 @@ class NetsimRunner(Runner):
                                        if traj.steps else None)})
         return final, traj
 
+# The train step rounds every value to its declared dtype.  By default XLA
+# may keep a bf16 intermediate in f32 inside a fusion ("excess
+# precision"), so whether a bf16 leaf diff or dequantized payload is
+# rounded depends on where fusions end.  The bucketed wire path hands
+# those values to Pallas kernels through HBM, rounded; the per-leaf path
+# fuses them and would not round, and the two modes' bf16 states drift
+# apart (by up to 0.04 in H and Hw after 4 steps of a bf16 qwen3 node).
+STEP_COMPILER_OPTIONS = {"xla_allow_excess_precision": False}
 
 
 class TrainerRunner(Runner):
@@ -926,6 +935,9 @@ class TrainerRunner(Runner):
         self.trainer = trainer
         self.spec = spec
         self._jit_step = None
+        self._jit_init = None
+        self._compiled_step = None
+        self._batch_sh = None
 
     # trainer passthroughs ---------------------------------------------------
     @property
@@ -938,14 +950,89 @@ class TrainerRunner(Runner):
     def batch_specs(self, batch_tree, node_axes: Tuple[str, ...]):
         return self.trainer.batch_specs(batch_tree, node_axes)
 
+    # placement ---------------------------------------------------------------
+    def _require_mesh(self):
+        want = self.spec.execution.mesh if self.spec is not None else None
+        if want is not None and self.mesh is None:
+            raise RuntimeError(
+                f"spec {self.spec.name!r} asks for mesh {want} but the "
+                f"runner was built without one ({len(jax.devices())} "
+                f"device(s) visible); refusing to run it on one device")
+
+    def _on_mesh(self):
+        from repro import compat
+        return (compat.set_mesh(self.mesh) if self.mesh is not None
+                else contextlib.nullcontext())
+
+    def _shardings(self, tree_specs):
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        return tmap(lambda sp: NamedSharding(self.mesh, sp), tree_specs,
+                    is_leaf=lambda x: isinstance(x, P))
+
+    def _node_axes(self):
+        from repro.models.sharding import node_axes
+        return node_axes(self.mesh)
+
+    def _step_fn(self, batch):
+        """The jitted train step.  On a mesh its operands and state output
+        are pinned to ``state_specs`` / ``batch_specs``, and the input
+        state is donated: at published widths a node's state is a third of
+        a chip, and the step's output reuses its buffers."""
+        if self._jit_step is None:
+            if self.mesh is None:
+                self._jit_step = jax.jit(self.trainer.train_step,
+                                         compiler_options=STEP_COMPILER_OPTIONS)
+            else:
+                naxes = self._node_axes()
+                state_sh = self._shardings(self.trainer.state_specs(naxes))
+                self._batch_sh = self._shardings(
+                    self.trainer.batch_specs(batch, naxes))
+                self._jit_step = jax.jit(
+                    self.trainer.train_step,
+                    in_shardings=(state_sh, self._batch_sh),
+                    out_shardings=(state_sh, None), donate_argnums=0,
+                    compiler_options=STEP_COMPILER_OPTIONS)
+        return self._jit_step
+
     # Runner protocol --------------------------------------------------------
     def init_state(self, key):
-        return self.trainer.init_state(key)
+        """Fresh state.  On a mesh every leaf is created under its
+        ``state_specs`` sharding: each node's replica on its own devices,
+        never gathered on one first."""
+        self._require_mesh()
+        if self.mesh is None:
+            return self.trainer.init_state(key)
+        if self._jit_init is None:
+            self._jit_init = jax.jit(
+                self.trainer.init_state, out_shardings=self._shardings(
+                    self.trainer.state_specs(self._node_axes())))
+        with self._on_mesh():
+            return self._jit_init(key)
+
+    def lower_step(self, state, batch):
+        """Trace and lower the train step for operands shaped like
+        ``state`` and ``batch`` (arrays or ShapeDtypeStructs)."""
+        self._require_mesh()
+        with self._on_mesh():
+            return self._step_fn(batch).lower(state, batch)
+
+    def compile_step(self, lowered):
+        """Compile a :meth:`lower_step` result; ``step`` runs this
+        executable from then on (returned so a caller can time the compile
+        and read ``as_text()``)."""
+        self._compiled_step = lowered.compile()
+        return self._compiled_step
 
     def step(self, state, batch):
-        if self._jit_step is None:
-            self._jit_step = jax.jit(self.trainer.train_step)
-        return self._jit_step(state, batch)
+        """One train step.  On a mesh ``state`` is donated: use the
+        returned state, not the one passed in."""
+        self._require_mesh()
+        fn = self._compiled_step or self._step_fn(batch)
+        if self.mesh is not None:
+            # each node's batch to its own devices, wherever it was made
+            batch = jax.device_put(batch, self._batch_sh)
+        with self._on_mesh():
+            return fn(state, batch)
 
     def run(self, *, num_steps: Optional[int] = None, data=None, state=None,
             key=None, callback=None, log_every: int = 0):
@@ -1007,15 +1094,19 @@ class TrainerRunner(Runner):
 
     def _wire_roofline(self, state, mean_step_s: float) -> dict:
         """Kernel/wire roofline for the bucketed gossip path (empty dict
-        when this trainer has no bucket layout to price)."""
+        when this trainer has no bucket layout to price, or the device has
+        no entry in ``obs.roofline.PEAKS``)."""
         tr = self.trainer
         from repro.core.compression import Identity
+        from repro.obs.roofline import peaks_for
+        peaks = peaks_for(jax.devices()[0].device_kind)
         if tr.plan is None or isinstance(tr.compressor, Identity) \
-                or tr.tcfg.wire_mode != "bucketed":
+                or tr.tcfg.wire_mode != "bucketed" or peaks is None:
             return {}
         layout, _model = obs.trainer_wire_layout(
             tr, jax.tree_util.tree_leaves(state.plead.X))
-        return obs.step_roofline(layout, hops=len(tr.plan.hops),
+        return obs.step_roofline(layout, peaks=peaks,
+                                 hops=len(tr.plan.hops),
                                  measured_step_s=mean_step_s or None)
 
     def default_data(self):

@@ -47,9 +47,24 @@ F64_RE = re.compile(r'\bf64\[')
 HOST_RE = re.compile(r'custom-call[^\n]*callback|\binfeed\(|\boutfeed\(')
 
 
+LAYOUT_RE = re.compile(r'\{[^{}]*\}')
+
+
 def collective_permute_shapes(hlo: str) -> List[str]:
-    """Result-shape strings of every collective-permute in ``hlo``."""
-    return [m.group(1) for m in CP_RE.finditer(hlo)]
+    """Payload shape strings of every collective-permute in ``hlo``.
+
+    Layouts are dropped first (a TPU layout such as ``{0:T(1024)(4,1)}``
+    holds parentheses).  An asynchronous ``collective-permute-start``
+    yields a tuple (operand, result, contexts...): its payload is the
+    operand."""
+    out = []
+    for m in CP_RE.finditer(LAYOUT_RE.sub("", hlo)):
+        shape = m.group(1)
+        if m.group(0).rstrip("(").endswith("-start") and \
+                shape.startswith("("):
+            shape = shape[1:].split(",", 1)[0].strip()
+        out.append(shape)
+    return out
 
 
 def _u8_bytes(shapes: Sequence[str]) -> float:

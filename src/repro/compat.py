@@ -1,64 +1,33 @@
-"""JAX version compatibility shims (supported: 0.4.3x and >= 0.6).
+"""The one door to JAX's sharding surface (written for JAX 0.9).
 
-The repo targets the new unified sharding APIs (``jax.make_mesh`` with
-``axis_types``, ``jax.set_mesh``, ``jax.shard_map``) but must also run on
-JAX 0.4.x, where those either don't exist or live under
-``jax.experimental`` with different keyword names.  Everything here is
-feature-detected at import time — no version-string parsing — so point
-releases that backport an API pick up the native path automatically.
+``jax.make_mesh`` axis types, ``jax.set_mesh`` and ``jax.shard_map`` are
+called here and nowhere else (the ``compat-only`` lint rule enforces it),
+so a JAX release that moves them changes one file.
 
-Policy (also recorded in CHANGES.md):
-
-* ``make_mesh(shape, axes, devices=...)`` — uses ``jax.sharding.AxisType``
-  Auto axis types when available; on 0.4.x plain ``jax.make_mesh`` (every
-  axis is implicitly auto there, which is the same behavior).
-* ``set_mesh(mesh)`` — context manager: ``jax.set_mesh`` when available,
-  else the classic ``Mesh`` context manager (``with mesh:``), which is what
-  0.4.x uses to establish the ambient mesh for ``with_sharding_constraint``.
-* ``shard_map(f, mesh, in_specs, out_specs, axis_names=...)`` — native
-  ``jax.shard_map`` when available; on 0.4.x
-  ``jax.experimental.shard_map.shard_map`` with the manual/auto split
-  expressed through ``auto = mesh axes - axis_names`` and ``check_vma``
-  mapped to ``check_rep``.
-* ``current_mesh()`` — the ambient (abstract or physical) mesh, or None.
+* ``make_mesh(shape, axes, devices=...)`` — every axis ``AxisType.Auto``.
+* ``set_mesh(mesh)`` — context manager making ``mesh`` the ambient mesh.
+* ``shard_map(f, mesh, in_specs, out_specs, axis_names=...)`` — partial-
+  manual: ``axis_names`` are the manual axes, the rest stay auto (GSPMD).
 """
 from __future__ import annotations
 
-import contextlib
 from typing import Any, Optional, Sequence
 
 import jax
 
-HAS_AXIS_TYPE = hasattr(jax.sharding, "AxisType")
-HAS_SET_MESH = hasattr(jax, "set_mesh")
-HAS_SHARD_MAP = hasattr(jax, "shard_map")
-
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
-              devices=None, explicit: Sequence[str] = ()):
-    """``jax.make_mesh`` with all-Auto axis types where supported.
-
-    ``explicit`` names axes to mark AxisType.Explicit on new JAX (ignored on
-    0.4.x, which has no sharding-in-types)."""
-    if HAS_AXIS_TYPE:
-        types = tuple(
-            jax.sharding.AxisType.Explicit if a in explicit
-            else jax.sharding.AxisType.Auto for a in axis_names)
-        return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
-                             axis_types=types, devices=devices)
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
-                         devices=devices)
+              devices=None):
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
+    return jax.make_mesh(
+        tuple(axis_shapes), tuple(axis_names),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
+        devices=devices)
 
 
 def set_mesh(mesh):
     """Context manager making ``mesh`` the ambient mesh for jit/lowering."""
-    if HAS_SET_MESH:
-        return jax.set_mesh(mesh)
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
-    if hasattr(mesh, "__enter__"):      # 0.4.x Mesh context manager
-        return mesh
-    return contextlib.nullcontext()
+    return jax.set_mesh(mesh)
 
 
 def shard_map(f, *, mesh, in_specs, out_specs,
@@ -67,24 +36,6 @@ def shard_map(f, *, mesh, in_specs, out_specs,
     """Partial-manual shard_map: ``axis_names`` are the manual axes, every
     other mesh axis stays auto (GSPMD)."""
     manual = set(axis_names) if axis_names is not None else set(mesh.axis_names)
-    if HAS_SHARD_MAP:
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=manual,
-                             check_vma=check)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    auto = frozenset(mesh.axis_names) - manual
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check, auto=auto)
-
-
-def current_mesh():
-    """The ambient mesh (entered via ``set_mesh``), or None."""
-    if hasattr(jax.sharding, "get_abstract_mesh"):
-        m = jax.sharding.get_abstract_mesh()
-        return m if m is not None and m.shape_tuple else None
-    try:  # 0.4.x: the Mesh context manager sets thread_resources
-        from jax._src import mesh as mesh_lib
-        m = mesh_lib.thread_resources.env.physical_mesh
-        return m if m is not None and not m.empty else None
-    except Exception:
-        return None
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, axis_names=manual,
+                         check_vma=check)

@@ -9,7 +9,9 @@ flat u8 wire buffers per node:
 
   codes buffer  — the nibble/byte-packed offset codes of every block of
                   every leaf, group by group, leaf by leaf;
-  scales buffer — one byte-cast scale (f32 or bf16) per block, same order.
+  scales buffer — one byte-cast scale (f32 or bf16) per block, same order;
+                  within a leaf's segment the scales' bytes are stored
+                  as planes (all least significant bytes first).
 
 A gossip hop then ppermutes those two buffers and nothing else: the COMM
 step costs 2 x hops collectives regardless of leaf count.  The layout
@@ -20,7 +22,9 @@ holds exactly the bytes the per-leaf path would have moved, concatenated.
 
 Everything here is static Python over leaf shapes; the jnp work (blocking,
 fused quantize+pack, fused unpack+dequant+mix) dispatches through
-:mod:`repro.kernels.ops`.
+:mod:`repro.kernels.ops`, one kernel call per leaf: a call over a whole
+group would hold f32 copies of every member leaf (and their noise) at
+once, which at published widths is more than a chip's memory.
 """
 from __future__ import annotations
 
@@ -96,7 +100,7 @@ def compute_layout(shapes: Sequence[Tuple[int, ...]],
 
     ``block_for(shape) -> int`` chooses each leaf's quantization block
     (default :func:`default_quant_block`); leaves sharing (block, dtype)
-    land in one group so a single fused kernel call covers them."""
+    land in one group, contiguous on the wire."""
     block_for = block_for or default_quant_block
     keys: List[Tuple[int, str]] = []        # group keys, first appearance
     members: List[List[int]] = []
@@ -145,25 +149,57 @@ def _scales_dtype(layout: BucketLayout):
     return jnp.bfloat16 if layout.scale_bytes == 2 else jnp.float32
 
 
-def pack_to_wire(layout: BucketLayout, xbs: Sequence[jax.Array],
-                 us: Sequence[jax.Array], *, use_pallas=None):
+def _uint(nbytes: int):
+    return jnp.uint16 if nbytes == 2 else jnp.uint32
+
+
+def _to_bytes(x: jax.Array) -> jax.Array:
+    """(R, 1) f32/bf16 scales -> (itemsize * R,) u8 byte planes: the R
+    least significant bytes, then the next R, and so on.  1-D planes made
+    by shifts: on TPU a u8 array with a minor dim of 4 (what a bitcast to
+    u8 gives) relays out through a program that takes minutes to
+    compile at published widths."""
+    nb = x.dtype.itemsize
+    w = jax.lax.bitcast_convert_type(x[:, 0], _uint(nb))
+    return jnp.concatenate([(w >> (8 * k)).astype(jnp.uint8)
+                            for k in range(nb)])
+
+
+def _from_bytes(b: jax.Array, rows: int, dtype) -> jax.Array:
+    """Inverse of :func:`_to_bytes`: (itemsize * rows,) u8 -> (rows,)
+    ``dtype``."""
+    nb = b.shape[0] // rows
+    ut = _uint(nb)
+    w = b[:rows].astype(ut)
+    for k in range(1, nb):
+        w = w | (b[k * rows:(k + 1) * rows].astype(ut) << (8 * k))
+    return jax.lax.bitcast_convert_type(w, dtype)
+
+
+def pack_to_wire(layout: BucketLayout, diffs: Sequence[jax.Array],
+                 keys: Sequence[jax.Array], *, use_pallas=None):
     """Quantize + pack every leaf into the two flat u8 wire buffers.
 
-    ``xbs[j]`` is leaf j blocked by :func:`kops.blockwise_lastdim` at its
-    slot's block width; ``us[j]`` is matching U[0,1) noise.  Returns
-    (codes u8 (codes_bytes,), scales u8 (scales_bytes,))."""
+    Leaf j is blocked by :func:`kops.blockwise_lastdim` at its slot's
+    block width and quantized with U[0,1) noise drawn from ``keys[j]`` at
+    that blocked shape — the per-leaf quantizer's exact draw.  The fused
+    kernel runs once per leaf, so its operands never exceed one leaf.
+    Rows land group by group, leaf by leaf.  Returns (codes u8
+    (codes_bytes,), scales u8 (scales_bytes,))."""
     codes_segs, scales_segs = [], []
     for g in layout.groups:
-        xr = jnp.concatenate(
-            [xbs[i].reshape(-1, g.block) for i in g.leaf_indices], axis=0)
-        ur = jnp.concatenate(
-            [us[i].reshape(-1, g.block) for i in g.leaf_indices], axis=0)
-        packed, scales = kops.qinf_quantize_pack(
-            xr, ur, bits=layout.bits, block=g.block, use_pallas=use_pallas)
-        scales = scales.astype(_scales_dtype(layout))
-        codes_segs.append(packed.reshape(-1))
-        scales_segs.append(
-            jax.lax.bitcast_convert_type(scales, jnp.uint8).reshape(-1))
+        for i in g.leaf_indices:
+            d = diffs[i]
+            # in the leaf's dtype: the kernel upcasts in VMEM, and an f32
+            # copy of a large leaf is what a chip runs short of
+            xb = kops.blockwise_lastdim(d, block=g.block, dtype=d.dtype)
+            u = jax.random.uniform(keys[i], xb.shape, jnp.float32)
+            packed, scales = kops.qinf_quantize_pack(
+                xb.reshape(-1, g.block), u.reshape(-1, g.block),
+                bits=layout.bits, block=g.block, use_pallas=use_pallas)
+            codes_segs.append(packed.reshape(-1))
+            scales_segs.append(_to_bytes(
+                scales.astype(_scales_dtype(layout))))
     return jnp.concatenate(codes_segs), jnp.concatenate(scales_segs)
 
 
@@ -186,26 +222,26 @@ def mix_from_wire(layout: BucketLayout, wires: Sequence[Tuple[jax.Array,
     S == len(wires), column order matching ``wires``.  Returns
     (wq leaves [(T, *shape) dtype], qself leaves [shape dtype]) in leaf
     order, where wq[t] = sum_s w[t, s] Q_s."""
-    S, T = len(wires), w.shape[0]
+    T = w.shape[0]
     sdtype = _scales_dtype(layout)
+    sb = layout.scale_bytes
     wq: list = [None] * len(layout.slots)
     qs: list = [None] * len(layout.slots)
     for g in layout.groups:
-        pw, sb = g.packed_width, layout.scale_bytes
-        pstack = jnp.stack([
-            c[g.codes_offset: g.codes_offset + g.rows * pw].reshape(
-                g.rows, pw) for c, _ in wires])
-        sstack = jnp.stack([
-            jax.lax.bitcast_convert_type(
-                s[g.scales_offset: g.scales_offset + g.rows * sb].reshape(
-                    g.rows, sb), sdtype).astype(jnp.float32)[:, None]
-            for _, s in wires])
-        mix, qself = kops.qinf_unpack_dequant_mix(
-            pstack, sstack, w, bits=layout.bits, block=g.block,
-            out_dtype=g.dtype, use_pallas=use_pallas)
-        for i in g.leaf_indices:
+        pw = g.packed_width
+        for i in g.leaf_indices:                # one kernel call per leaf
             sl = layout.slots[i]
-            r0, r1 = sl.row_offset, sl.row_offset + sl.rows
-            wq[i] = rows_to_leaf(sl, mix[:, r0:r1], lead=(T,))
-            qs[i] = rows_to_leaf(sl, qself[r0:r1])
+            c0 = g.codes_offset + sl.row_offset * pw
+            s0 = g.scales_offset + sl.row_offset * sb
+            pstack = jnp.stack([c[c0: c0 + sl.rows * pw].reshape(sl.rows, pw)
+                                for c, _ in wires])
+            sstack = jnp.stack([
+                _from_bytes(s[s0: s0 + sl.rows * sb], sl.rows,
+                            sdtype).astype(jnp.float32)[:, None]
+                for _, s in wires])
+            mix, qself = kops.qinf_unpack_dequant_mix(
+                pstack, sstack, w, bits=layout.bits, block=g.block,
+                out_dtype=g.dtype, use_pallas=use_pallas)
+            wq[i] = rows_to_leaf(sl, mix, lead=(T,))
+            qs[i] = rows_to_leaf(sl, qself)
     return wq, qs

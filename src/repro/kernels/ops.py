@@ -40,18 +40,22 @@ def _use_pallas_default() -> bool:
 # tensor (leading dims — node, layer — pass through untouched).
 # ---------------------------------------------------------------------------
 
-def blockwise_lastdim(x: jax.Array, *, block: int) -> jax.Array:
-    """(..., D) -> (..., nb, block) f32, zero-padded along the last axis.
+def blockwise_lastdim(x: jax.Array, *, block: int,
+                      dtype=jnp.float32) -> jax.Array:
+    """(..., D) -> (..., nb, block) ``dtype``, zero-padded along the last
+    axis.
 
     The exact reshape ``qinf_quantize_lastdim`` quantizes — factored out so
     the bucketed wire path blocks its leaves (and draws stochastic-rounding
-    noise of the same shape) bit-for-bit like the per-leaf path."""
+    noise of the same shape) bit-for-bit like the per-leaf path.  The
+    quantizers compute in f32; a narrower ``dtype`` (the leaf's own) only
+    defers that exact upcast to the kernel."""
     if x.ndim == 0:
         x = x[None]
     D = x.shape[-1]
     nb = -(-D // block)
     pad = nb * block - D
-    xf = x.astype(jnp.float32)
+    xf = x.astype(dtype)
     if pad:
         xf = jnp.pad(xf, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
     return xf.reshape(*x.shape[:-1], nb, block)
@@ -204,7 +208,7 @@ def qinf_quantize_pack(xrows: jax.Array, urows: jax.Array, *, bits: int,
     R = xrows.shape[0]
     Rp = -(-R // qk.ROWS_TILE) * qk.ROWS_TILE
     packed, scales = qk.qinf_quantize_pack_blocks(
-        _pad_rows(xrows.astype(jnp.float32), Rp), _pad_rows(urows, Rp),
+        _pad_rows(xrows, Rp), _pad_rows(urows, Rp),
         bits=bits, block=block, interpret=_interpret_default())
     return packed[:R], scales[:R]
 

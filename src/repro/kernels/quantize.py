@@ -19,12 +19,28 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.ref import wire_bits_per_element
+from repro.kernels.ref import (mix_sum, payload_parts, split_hi_lo,
+                               wire_bits_per_element)
 
 # One quantization block per row; 256 matches the paper's block size and is a
 # multiple of the 128-lane VPU width.
 ROWS_TILE = 8  # sublane tile: f32 min tile is (8, 128)
+
+
+# BlockSpec index maps.  Their indices are int32 even under
+# jax_enable_x64: Mosaic refuses an index map that returns an i64.
+def _row_tile(i):
+    return i, jnp.int32(0)
+
+
+def _sender_row_tile(i):
+    return jnp.int32(0), i, jnp.int32(0)
+
+
+def _whole(i):
+    return jnp.int32(0), jnp.int32(0)
 
 
 def packed_width(block: int, bits: int) -> int:
@@ -65,12 +81,12 @@ def qinf_quantize_blocks(xb: jax.Array, ub: jax.Array, *, bits: int,
         functools.partial(_quantize_kernel, bits=bits),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((ROWS_TILE, block), lambda i: (i, 0)),
-            pl.BlockSpec((ROWS_TILE, block), lambda i: (i, 0)),
+            pl.BlockSpec((ROWS_TILE, block), _row_tile),
+            pl.BlockSpec((ROWS_TILE, block), _row_tile),
         ],
         out_specs=[
-            pl.BlockSpec((ROWS_TILE, block), lambda i: (i, 0)),
-            pl.BlockSpec((ROWS_TILE, 1), lambda i: (i, 0)),
+            pl.BlockSpec((ROWS_TILE, block), _row_tile),
+            pl.BlockSpec((ROWS_TILE, 1), _row_tile),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((R, block), jnp.int8),
@@ -113,32 +129,39 @@ def _quantize_pack_kernel(x_ref, u_ref, packed_ref, scales_ref, *, bits: int):
 
 def _unpack_dequant_mix_kernel(p_ref, s_ref, w_ref, mix_ref, qself_ref, *,
                                bits: int, out_dtype):
-    p = p_ref[...].astype(jnp.int32)             # (S, ROWS_TILE, W)
+    S, T = p_ref.shape[0], mix_ref.shape[0]
     offset = jnp.int32(2 ** (bits - 1))
-    if wire_bits_per_element(bits) == 4:
-        lo = (p & 0x0F) - offset
-        hi = ((p >> 4) & 0x0F) - offset
-        codes = jnp.concatenate([lo, hi], axis=-1).astype(jnp.float32)
-    else:
-        codes = (p - offset).astype(jnp.float32)
-    q = codes * s_ref[...].astype(jnp.float32)   # (S, ROWS_TILE, BLOCK)
-    # round each sender's dequantized payload through the leaf dtype before
-    # the f32 accumulation — bit-for-bit what the per-leaf path computes
-    # when it stacks dequantized leaves
-    q = q.astype(out_dtype).astype(jnp.float32)
-    w = w_ref[...].astype(jnp.float32)           # (T, S)
-    # dot over the S senders, not an unrolled madd chain — matches the
-    # per-leaf path's accumulation exactly (see kernels.ref.weighted_mix_ref)
-    mix_ref[...] = jnp.tensordot(w, q, axes=(1, 0)).astype(out_dtype)
-    qself_ref[...] = q[0].astype(out_dtype)
+    qs = []
+    for s in range(S):                           # 2-D (ROWS_TILE, W) tiles
+        p = p_ref[s].astype(jnp.int32)
+        if wire_bits_per_element(bits) == 4:
+            lo = (p & 0x0F) - offset
+            hi = ((p >> 4) & 0x0F) - offset
+            codes = jnp.concatenate([lo, hi], axis=-1).astype(jnp.float32)
+        else:
+            codes = (p - offset).astype(jnp.float32)
+        q = codes * s_ref[s].astype(jnp.float32)     # (ROWS_TILE, BLOCK)
+        # round each sender's dequantized payload through the leaf dtype
+        # before the f32 accumulation — bit-for-bit what the per-leaf path
+        # computes when it dequantizes whole leaves
+        qs.append(q.astype(out_dtype).astype(jnp.float32))
+    # kernels.ref.mix_sum, the per-leaf path's sum of exact partial
+    # products, on 2-D tiles; the weights' (hi | lo) halves are SMEM scalars
+    parts = [payload_parts(q, out_dtype) for q in qs]
+    for t in range(T):
+        mix_ref[t] = mix_sum([w_ref[t, s] for s in range(S)],
+                             [w_ref[t, S + s] for s in range(S)],
+                             parts).astype(out_dtype)
+    qself_ref[...] = qs[0].astype(out_dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "block", "interpret"))
 def qinf_quantize_pack_blocks(xb: jax.Array, ub: jax.Array, *, bits: int,
                               block: int = 256, interpret: bool = True):
-    """Fused quantize+pack: (R, block) rows -> (packed u8 (R, W), scales f32
-    (R, 1)), W = packed_width(block, bits).  R % ROWS_TILE == 0 (callers pad
-    for the kernel and slice the output; padded rows never reach the wire).
+    """Fused quantize+pack: (R, block) float rows (upcast to f32 in VMEM)
+    -> (packed u8 (R, W), scales f32 (R, 1)), W = packed_width(block,
+    bits).  R % ROWS_TILE == 0 (callers pad for the kernel and slice the
+    output; padded rows never reach the wire).
     """
     R, B = xb.shape
     assert B == block, (xb.shape, block)
@@ -149,12 +172,12 @@ def qinf_quantize_pack_blocks(xb: jax.Array, ub: jax.Array, *, bits: int,
         functools.partial(_quantize_pack_kernel, bits=bits),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((ROWS_TILE, block), lambda i: (i, 0)),
-            pl.BlockSpec((ROWS_TILE, block), lambda i: (i, 0)),
+            pl.BlockSpec((ROWS_TILE, block), _row_tile),
+            pl.BlockSpec((ROWS_TILE, block), _row_tile),
         ],
         out_specs=[
-            pl.BlockSpec((ROWS_TILE, W), lambda i: (i, 0)),
-            pl.BlockSpec((ROWS_TILE, 1), lambda i: (i, 0)),
+            pl.BlockSpec((ROWS_TILE, W), _row_tile),
+            pl.BlockSpec((ROWS_TILE, 1), _row_tile),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((R, W), jnp.uint8),
@@ -184,20 +207,21 @@ def qinf_unpack_dequant_mix_blocks(packed: jax.Array, scales: jax.Array,
                           out_dtype=out_dtype),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((S, ROWS_TILE, W), lambda i: (0, i, 0)),
-            pl.BlockSpec((S, ROWS_TILE, 1), lambda i: (0, i, 0)),
-            pl.BlockSpec((T, S), lambda i: (0, 0)),
+            pl.BlockSpec((S, ROWS_TILE, W), _sender_row_tile),
+            pl.BlockSpec((S, ROWS_TILE, 1), _sender_row_tile),
+            pl.BlockSpec((T, 2 * S), _whole, memory_space=pltpu.SMEM),
         ],
         out_specs=[
-            pl.BlockSpec((T, ROWS_TILE, block), lambda i: (0, i, 0)),
-            pl.BlockSpec((ROWS_TILE, block), lambda i: (i, 0)),
+            pl.BlockSpec((T, ROWS_TILE, block), _sender_row_tile),
+            pl.BlockSpec((ROWS_TILE, block), _row_tile),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((T, R, block), out_dtype),
             jax.ShapeDtypeStruct((R, block), out_dtype),
         ],
         interpret=interpret,
-    )(packed, scales, w)
+    )(packed, scales, jnp.concatenate(split_hi_lo(w.astype(jnp.float32)),
+                                      axis=1))
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret", "out_dtype"))
@@ -212,10 +236,10 @@ def qinf_dequantize_blocks(codes: jax.Array, scales: jax.Array, *,
         functools.partial(_dequantize_kernel, out_dtype=out_dtype),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((ROWS_TILE, block), lambda i: (i, 0)),
-            pl.BlockSpec((ROWS_TILE, 1), lambda i: (i, 0)),
+            pl.BlockSpec((ROWS_TILE, block), _row_tile),
+            pl.BlockSpec((ROWS_TILE, 1), _row_tile),
         ],
-        out_specs=pl.BlockSpec((ROWS_TILE, block), lambda i: (i, 0)),
+        out_specs=pl.BlockSpec((ROWS_TILE, block), _row_tile),
         out_shape=jax.ShapeDtypeStruct((R, block), out_dtype),
         interpret=interpret,
     )(codes, scales)

@@ -7,6 +7,7 @@ these oracles directly — interpret-mode Pallas is for parity tests only.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
@@ -92,18 +93,68 @@ def qinf_quantize_pack_blocks_ref(xb: jnp.ndarray, ub: jnp.ndarray,
     return pack_codes_halves_ref(codes, bits), scales
 
 
-def weighted_mix_ref(w: jnp.ndarray, q: jnp.ndarray) -> jnp.ndarray:
-    """out[t] = sum_s w[t, s] * q[s] in f32, as a dot over the sender axis.
+def split_hi_lo(x: jnp.ndarray):
+    """f32 ``x`` -> (hi, lo), hi + lo == x exactly, each part of at most
+    12 significant bits (hi clears x's 12 low mantissa bits), so the
+    product of two parts is exact in f32.  Made from x's bits, never from
+    x itself: lo is (sign, exponent, low bits) minus (sign, exponent),
+    an exact difference of two bit patterns that no compiler can fuse
+    with the multiply that produced x."""
+    u = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    f = lambda b: jax.lax.bitcast_convert_type(b, jnp.float32)
+    e = u & jnp.uint32(0xFF800000)
+    return (f(u & jnp.uint32(0xFFFFF000)),
+            f(e | (u & jnp.uint32(0x00000FFF))) - f(e))
 
-    A dot (not an unrolled multiply-add chain) on purpose: XLA's dot
-    emitter accumulates the S-length contraction identically whatever the
-    non-contracted shape, whereas an elementwise madd chain gets FMA-
-    contracted shape-dependently by the CPU backend — the bucketed and
-    per-leaf wire paths mix differently-shaped views of the same payloads
-    and must agree bit for bit.  ``w`` (T, S), ``q`` (S, ...) -> (T, ...).
+
+def payload_parts(q: jnp.ndarray, dtype):
+    """A sender's dequantized payload (f32 values of leaf ``dtype``) as
+    the parts :func:`mix_sum` multiplies: whole when ``dtype`` carries at
+    most 12 significant bits (bf16: 8), else :func:`split_hi_lo`."""
+    if jnp.finfo(dtype).nmant + 1 <= 12:
+        return (q,)
+    return split_hi_lo(q)
+
+
+def mix_sum(w_hi, w_lo, q_parts):
+    """sum_s (w_hi[s] + w_lo[s]) * sum(q_parts[s]), added up in a fixed
+    order of EXACT partial products (every part has <= 12 significant
+    bits).  Each add rounds once, and a multiply contracted into the next
+    add (an FMA) rounds no differently — so any compiler, and the Pallas
+    mix kernel, gives the same bits.  ``w_hi``/``w_lo`` index to scalars
+    (or broadcastable arrays).
+
+    The order runs over senders innermost, so the first add joins two
+    senders' products, which share no operand: XLA:TPU rewrites
+    ``a*c + b*c`` into ``(a + b)*c`` (rounded, not exact), and a sender's
+    hi and lo products would be such a pair."""
+    terms = [wk[s] * parts[k]
+             for wk in (w_hi, w_lo)
+             for k in range(len(q_parts[0]))
+             for s, parts in enumerate(q_parts)]
+    acc = terms[0]
+    for term in terms[1:]:
+        acc = acc + term
+    return acc
+
+
+def weighted_mix_ref(w: jnp.ndarray, q, dtype) -> jnp.ndarray:
+    """out[t] = sum_s w[t, s] * q[s] in f32 via :func:`mix_sum`.
+
+    Not a dot: a dot leaves its accumulation to the compiler, and on a
+    TPU v5e XLA turns a one-round (1, S) dot into a multiply and a reduce
+    while Mosaic runs it through the MXU in bf16 passes — they differ in
+    the last ulp for weights such as 1/3.  Nor a plain multiply-add
+    chain: XLA's CPU backend contracts a product into the following add
+    (FMA) and picks which of two products to fuse by the loop's shape.
+    ``w`` (T, S); ``q`` (S, ...) or a sequence of S arrays holding values
+    of leaf ``dtype`` -> (T, ...) f32.
     """
-    return jnp.tensordot(w.astype(jnp.float32), q.astype(jnp.float32),
-                         axes=(1, 0))
+    w_hi, w_lo = split_hi_lo(w.astype(jnp.float32))
+    parts = [payload_parts(q[s].astype(jnp.float32), dtype)
+             for s in range(w.shape[1])]
+    return jnp.stack([mix_sum(w_hi[t], w_lo[t], parts)
+                      for t in range(w.shape[0])])
 
 
 def qinf_unpack_dequant_mix_blocks_ref(packed: jnp.ndarray,
@@ -117,10 +168,10 @@ def qinf_unpack_dequant_mix_blocks_ref(packed: jnp.ndarray,
     round.  Returns (mix (T, R, B) out_dtype, qself (R, B) out_dtype) where
     mix[t] = sum_s w[t, s] * Q_s.  Each Q_s rounds through ``out_dtype``
     before the f32 accumulation — exactly what the per-leaf path does when
-    it stacks dequantized leaves — so the two wire modes agree bit for bit.
+    it dequantizes whole leaves — so the two wire modes agree bit for bit.
     """
     codes = unpack_codes_halves_ref(packed, bits).astype(jnp.float32)
     q = codes * scales.astype(jnp.float32)            # (S, R, B)
     q = q.astype(out_dtype).astype(jnp.float32)
-    mix = weighted_mix_ref(w, q)
+    mix = weighted_mix_ref(w, q, out_dtype)
     return mix.astype(out_dtype), q[0].astype(out_dtype)

@@ -196,8 +196,10 @@ def run_one(arch: str, shape_name: str, *, multi_pod=False, backend="dense",
 
         mem = compiled.memory_analysis()
         n_active = cfg.param_count(active_only=True)
+        # the dry run models a v5e pod (launch/mesh.py)
         rl = roofline.analyze(compiled, cfg, shape,
-                              mesh_mod.n_nodes(mesh), chips)
+                              mesh_mod.n_nodes(mesh), chips,
+                              peaks=roofline.PEAKS["TPU v5 lite"])
         rec.update({
             "status": "ok",
             "t_lower_s": round(t_lower, 1),

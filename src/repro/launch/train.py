@@ -18,9 +18,11 @@ import time
 import jax
 
 from repro import api
+from repro.launch.cache import use_compile_cache
 
 
 def main(argv=None):
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-1.7b")
     ap.add_argument("--nodes", type=int, default=8)
@@ -85,8 +87,7 @@ def main(argv=None):
                 if args.compressor == "qinf" else args.compressor)
         print(f"done: {args.steps} steps; ~{comm_gb:.3f} GB "
               f"communicated/node ({desc}); "
-              f"wire fraction {rep.timing['wire_fraction_of_step']:.1%} "
-              f"of {rep.timing['mean_step_s']:.2f}s/step")
+              f"{rep.timing['mean_step_s']:.2f}s/step")
     else:
         print("done")
     if args.report and rep is not None:

@@ -1,21 +1,16 @@
-"""Logical-axis sharding: activation constraints + parameter PartitionSpecs.
+"""Parameter PartitionSpecs and mesh-axis helpers.
 
 Mesh axes:
   node axes  — ('data',) single-pod, ('pod','data') multi-pod: the
                decentralized graph (leading N dim on training state)
   'model'    — tensor parallelism inside each node
-
-Logical activation axes -> mesh axes:
-  "node" -> node axes, "batch" -> node axes (serving), "heads"/"ff"/"vocab"
-  -> 'model', everything else replicated.
 """
 from __future__ import annotations
 
 import re
-from typing import Any, Optional, Sequence, Tuple
+from typing import Any, Sequence, Tuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
@@ -47,19 +42,6 @@ def model_local_shape(shape, spec: P, model: int):
         sharded = "model" in (e if isinstance(e, tuple) else (e,))
         local.append(dim // model if sharded else dim)
     return tuple(local)
-
-
-def constrain(x, spec: Optional[P]):
-    """with_sharding_constraint if a concrete mesh is active, else no-op."""
-    if spec is None:
-        return x
-    try:
-        from repro import compat
-        if compat.current_mesh() is None:
-            return x
-        return jax.lax.with_sharding_constraint(x, spec)
-    except Exception:
-        return x
 
 
 # ---------------------------------------------------------------------------

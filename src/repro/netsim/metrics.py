@@ -89,15 +89,14 @@ def _model_local_shapes(trainer, leaves):
     """(model_size, per-device leaf shapes) as the full-manual shard_map
     sees them.
 
-    Under the jax 0.4.x full-manual fallback (and the always-full-manual
-    bucketed wire mode) a node spans model_size devices and each device
-    ppermutes its LOCAL arrays: leaves whose last dim is model-sharded
+    Under the full-manual bucketed wire mode a node spans model_size
+    devices and each device ppermutes its LOCAL arrays: leaves whose last dim is model-sharded
     quantize (and pad) per slice, every other leaf is ppermuted redundantly
     by all model_size devices — the physical edge payload is model_size x
     the per-device bytes (which is what the HLO's collective-permutes show,
     per device)."""
-    # the trainer's own predicate: full-manual on 0.4.x always and for
-    # the bucketed wire path on any JAX (identity is always per-leaf)
+    # the trainer's own predicate: full-manual for the bucketed wire path
+    # (identity is always per-leaf)
     full_manual = not trainer._partial_manual
     model = 1
     locals_ = [l.shape[1:] for l in leaves]      # per-node leaf shapes

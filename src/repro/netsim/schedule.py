@@ -222,20 +222,25 @@ class ScheduledMixer(Mixer):
 
     def __init__(self, schedule: TopologySchedule):
         self.schedule = schedule
-        self._stacks = {}            # dtype name -> (T, n, n) jnp constant
+        self._stacks = {}            # dtype name -> (T, n, n) numpy constant
 
-    def materialized(self, dtype) -> jnp.ndarray:
+    def materialized(self, dtype) -> np.ndarray:
+        # a NumPy stack built at compile time: the cache outlives the jit
+        # trace that first fills it, and a value computed under that trace
+        # would be its tracer
         key = jnp.dtype(dtype).name
         if key not in self._stacks:
-            self._stacks[key] = jnp.stack([
-                _exact_stochastic(self.schedule.W_stack[t], dtype)
-                for t in range(self.schedule.T_cycle)])
+            with jax.ensure_compile_time_eval():
+                self._stacks[key] = np.stack([
+                    np.asarray(_exact_stochastic(self.schedule.W_stack[t],
+                                                 dtype))
+                    for t in range(self.schedule.T_cycle)])
         return self._stacks[key]
 
     def W_k(self, k, dtype):
         idx = (jnp.int32(0) if k is None
                else jnp.asarray(k, jnp.int32) % self.schedule.T_cycle)
-        return self.materialized(dtype)[idx]
+        return jnp.asarray(self.materialized(dtype))[idx]
 
     def __call__(self, X, k=None):
         def mix_leaf(leaf):

@@ -13,9 +13,9 @@ Three layers (ISSUE 6 / ROADMAP "Roofline-gated perf CI"):
   time.
 
 * structured reports (:mod:`repro.obs.report`) — every ``Runner.run``
-  emits a :class:`RunReport` (JSON-serializable) with a compute-vs-wire
-  step-time breakdown and the exact bits on the wire, stored on the
-  runner as ``last_report``.
+  emits a :class:`RunReport` (JSON-serializable) with the measured step
+  time and the exact bits on the wire, stored on the runner as
+  ``last_report``.
 
 * roofline comparison (:mod:`repro.obs.roofline_gate`) — analytical
   HBM/link rooflines for the fused wire kernels, derived from the exact

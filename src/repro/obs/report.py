@@ -9,12 +9,10 @@ env        :func:`repro.obs.meters.env_info` stamp (jax version, backend,
            device kind/count, cpu count, x64) — history comparisons stay
            attributable across machines.
 timing     measured total + per-step wall clock (block_until_ready-
-           correct), split compute-vs-wire: ``wire_model_s_per_step`` is
-           the exact bits on the wire pushed through one
-           ``src/repro/obs/roofline.py::LINK_BW`` link, ``compute_residual_s_per_
-           step`` is the measured remainder.  An analytic split, not a
-           profile: it answers "at hardware link speed, what fraction of
-           this step is communication?"
+           correct).  On a device in ``obs.roofline.PEAKS`` it also holds
+           ``wire_model_s_per_step``: the exact bits on the wire priced at
+           that chip's published interconnect bandwidth — a model, not a
+           measurement.  On any other device the key is absent.
 wire       the exact accounting: ``bits_per_step`` / ``bits_total`` from
            the same functions the tests pin against HLO-parsed collective
            bytes (``netsim.metrics``), plus the WireExchange gauges
@@ -24,7 +22,8 @@ wire       the exact accounting: ``bits_per_step`` / ``bits_total`` from
            netsim trajectory convention).
 meters     raw snapshot of the run's :class:`~repro.obs.meters.Meters`.
 roofline   :func:`repro.obs.roofline_gate.step_roofline` output when the
-           engine has a bucket layout (sharded trainer), else empty.
+           engine has a bucket layout (sharded trainer) and the device is
+           in ``obs.roofline.PEAKS``, else empty.
 extra      engine-specific fields (algo name, final consensus, ...).
 =========  ================================================================
 """
@@ -35,8 +34,8 @@ import json
 import pathlib
 from typing import Any, Dict, Optional
 
-from repro.obs.roofline import LINK_BW
 from repro.obs.meters import Meters, env_info
+from repro.obs.roofline import Peaks, peaks_for
 
 
 @dataclasses.dataclass
@@ -76,20 +75,15 @@ class RunReport:
         return cls.from_dict(json.loads(text))
 
 
-def wire_breakdown(total_s: float, steps: int,
-                   bits_per_step: float) -> Dict[str, float]:
-    """The compute-vs-wire split: measured mean step time vs the analytic
-    link time for the exact per-step bits (see module docstring)."""
-    mean = total_s / steps if steps else 0.0
-    wire_model = (bits_per_step / 8.0) / LINK_BW
-    return {
-        "total_s": float(total_s),
-        "mean_step_s": mean,
-        "wire_model_s_per_step": wire_model,
-        "compute_residual_s_per_step": max(0.0, mean - wire_model),
-        "wire_fraction_of_step": (min(1.0, wire_model / mean)
-                                  if mean > 0 else 0.0),
-    }
+def wire_breakdown(total_s: float, steps: int, bits_per_step: float,
+                   peaks: Optional[Peaks] = None) -> Dict[str, float]:
+    """Measured total and mean step time, plus the modelled wire time of
+    the exact per-step bits when the chip's ``peaks`` are known."""
+    out = {"total_s": float(total_s),
+           "mean_step_s": total_s / steps if steps else 0.0}
+    if peaks is not None:
+        out["wire_model_s_per_step"] = (bits_per_step / 8.0) / peaks.ici_bw
+    return out
 
 
 def build_report(*, name: str, engine: str, steps: int, total_s: float,
@@ -112,8 +106,10 @@ def build_report(*, name: str, engine: str, steps: int, total_s: float,
         "hops": m.get("wire/hops", 0),
         "collectives_per_step": m.get("wire/collectives_per_step", 0),
     }
+    env = env_info()
     return RunReport(
-        name=name, engine=engine, steps=int(steps), env=env_info(),
-        timing=wire_breakdown(total_s, steps, bits_per_step),
+        name=name, engine=engine, steps=int(steps), env=env,
+        timing=wire_breakdown(total_s, steps, bits_per_step,
+                              peaks_for(env["device_kind"])),
         wire=wire, meters=m, roofline=dict(roofline or {}),
         extra=dict(extra or {}))

@@ -10,7 +10,7 @@ Three terms per (arch, shape, mesh), all in seconds (per chip):
 
     compute    = FLOPs / peak_FLOP/s
     memory     = HBM traffic / HBM_bw
-    collective = wire bytes x ring factor / link_bw
+    collective = wire bytes x ring factor / interconnect bw
 
 METHODOLOGY (and why it is what it is):
 
@@ -34,19 +34,41 @@ METHODOLOGY (and why it is what it is):
   collective bytes for bf16 tensors are ~2x TPU wire bytes.  Ratios between
   variants are unaffected; absolute terms are conservative upper bounds.
 
-Hardware constants: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link.
+Hardware peaks: :data:`PEAKS`, one entry per ``jax.Device.device_kind``,
+each with its source.  A device missing from the table has no roofline.
 """
 from __future__ import annotations
 
 import dataclasses
 import re
-from typing import Dict, List, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-LINK_BW = 50e9
+
+@dataclasses.dataclass(frozen=True)
+class Peaks:
+    """Published peaks of ONE chip."""
+    flops: float        # dense bf16 FLOP/s
+    hbm_bw: float       # HBM bytes/s
+    ici_bw: float       # chip-to-chip interconnect bytes/s, all links
+    source: str
+
+
+# keyed by jax's ``Device.device_kind``
+PEAKS: Dict[str, Peaks] = {
+    "TPU v5 lite": Peaks(                            # TPU v5e
+        flops=197e12, hbm_bw=819e9, ici_bw=1600e9 / 8,
+        source="Google Cloud documentation, 'TPU v5e': 197 TFLOP/s bf16, "
+               "16 GB HBM at 819 GB/s, 1,600 Gbit/s interchip "
+               "interconnect"),
+}
+
+
+def peaks_for(device_kind: str) -> Optional[Peaks]:
+    """The table entry for ``device_kind``, or None: a device the table
+    does not know gets no roofline, never another chip's peaks."""
+    return PEAKS.get(device_kind)
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -276,18 +298,19 @@ class Roofline:
     model_flops_per_chip: float    # 6ND / 2ND only (no attention terms)
     hlo_flops: float               # raw cost_analysis (loop-undercounted)
     hlo_bytes: float
+    peaks: Peaks
 
     @property
     def t_compute(self):
-        return self.flops_per_chip / PEAK_FLOPS
+        return self.flops_per_chip / self.peaks.flops
 
     @property
     def t_memory(self):
-        return self.hbm_bytes_per_chip / HBM_BW
+        return self.hbm_bytes_per_chip / self.peaks.hbm_bw
 
     @property
     def t_collective(self):
-        return self.coll_bytes / LINK_BW
+        return self.coll_bytes / self.peaks.ici_bw
 
     @property
     def bottleneck(self):
@@ -324,8 +347,8 @@ def model_flops(cfg, shape, n_params_active: int) -> float:
     return 2.0 * n_params_active * shape.global_batch
 
 
-def analyze(compiled, cfg, shape, n_nodes: int, n_chips: int,
-            state_copies: float = 4.0) -> Roofline:
+def analyze(compiled, cfg, shape, n_nodes: int, n_chips: int, *,
+            peaks: Peaks, state_copies: float = 4.0) -> Roofline:
     ca = compiled.cost_analysis()
     if isinstance(ca, (list, tuple)):
         ca = ca[0]
@@ -340,4 +363,5 @@ def analyze(compiled, cfg, shape, n_nodes: int, n_chips: int,
         model_flops_per_chip=model_flops(cfg, shape, n_active) / n_chips,
         hlo_flops=float(ca.get("flops", 0.0)),
         hlo_bytes=float(ca.get("bytes accessed", 0.0)),
+        peaks=peaks,
     )

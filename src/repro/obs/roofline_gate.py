@@ -20,27 +20,27 @@ move through HBM even though they never ship):
 * unpack_dequant_mix — reads ``1 + hops`` received payload pairs, writes
   the f32 mix for each of ``receivers`` rows plus the f32 qself rows
   (``(receivers + 1) x 4 x elems``).
-* wire — ``hops`` serial link transfers of ``codes_bytes + scales_bytes``
+* wire — ``hops`` serial transfers of ``codes_bytes + scales_bytes``
   each (the exact bits :func:`repro.netsim.metrics.bucketed_payload_bits`
-  counts, divided by the model-shard redundancy).
+  counts, divided by the model-shard redundancy), priced at the chip's
+  whole interconnect bandwidth: a lower bound on the time.
 
-Hardware constants come from ``obs/roofline.py`` (TPU v5e).  On the
-CPU test backend measured times are far off the TPU roofline — the
-*ratios* and the byte equalities are the portable, gateable part.
+The peaks are a :class:`repro.obs.roofline.Peaks` entry of the chip the
+model is for; every second here is modelled, none is measured.
 """
 from __future__ import annotations
 
 from typing import Dict, Optional, Tuple
 
 from repro.core.bucket import BucketLayout
-from repro.obs.roofline import HBM_BW, LINK_BW
+from repro.obs.roofline import Peaks
 
 
 def _elems(layout: BucketLayout) -> int:
     return sum(g.rows * g.block for g in layout.groups)
 
 
-def kernel_roofline(layout: BucketLayout, *, hops: int = 1,
+def kernel_roofline(layout: BucketLayout, *, peaks: Peaks, hops: int = 1,
                     receivers: int = 1) -> Dict[str, Dict[str, float]]:
     """Predicted HBM bytes and roofline seconds per kernel (one node, one
     COMM exchange).  See the module docstring for the traffic model."""
@@ -50,20 +50,21 @@ def kernel_roofline(layout: BucketLayout, *, hops: int = 1,
     um_bytes = (1 + hops) * wire_bytes + (receivers + 1) * 4 * elems
     return {
         "quantize_pack": {"hbm_bytes": float(qp_bytes),
-                          "t_s": qp_bytes / HBM_BW},
+                          "t_s": qp_bytes / peaks.hbm_bw},
         "unpack_dequant_mix": {"hbm_bytes": float(um_bytes),
-                               "t_s": um_bytes / HBM_BW},
+                               "t_s": um_bytes / peaks.hbm_bw},
         "wire": {"bytes_per_hop": float(wire_bytes), "hops": float(hops),
-                 "t_s": hops * wire_bytes / LINK_BW},
+                 "t_s": hops * wire_bytes / peaks.ici_bw},
     }
 
 
-def step_roofline(layout: BucketLayout, *, hops: int, receivers: int = 1,
+def step_roofline(layout: BucketLayout, *, peaks: Peaks, hops: int,
+                  receivers: int = 1,
                   measured_step_s: Optional[float] = None) -> Dict:
     """Whole-exchange roofline: kernel + wire seconds, plus
     ``utilization = predicted / measured`` when a measured step time is
-    given (1.0 = running at the roofline; CPU runs sit far below)."""
-    k = kernel_roofline(layout, hops=hops, receivers=receivers)
+    given (1.0 = running at the roofline)."""
+    k = kernel_roofline(layout, peaks=peaks, hops=hops, receivers=receivers)
     wire_s = k["wire"]["t_s"]
     kernel_s = k["quantize_pack"]["t_s"] + k["unpack_dequant_mix"]["t_s"]
     out = {

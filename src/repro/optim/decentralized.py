@@ -24,12 +24,12 @@ Wire modes on the neighbor backend (wire_mode):
              regardless of leaf count, and quantize+pack / unpack+dequant+
              mix run as fused kernels (repro.kernels).  Bit-for-bit equal
              to per_leaf whenever both modes run the same shard_map
-             manualness: all of JAX 0.4.x, and model-unsharded meshes on
-             >= 0.6.  On >= 0.6 with a model-sharded mesh the per-leaf
-             mode stays partial-manual (full leaves, one noise draw) while
-             bucketed is full-manual (per-shard slices, per-shard draws),
-             so the stochastic-rounding streams differ — equal in
-             distribution, not bitwise.
+             manualness, i.e. on model-unsharded meshes.  On a
+             model-sharded mesh the per-leaf mode stays partial-manual
+             (full leaves, one noise draw) while bucketed is full-manual
+             (per-shard slices, per-shard draws), so the stochastic-
+             rounding streams differ — equal in distribution, not
+             bitwise.
   per_leaf — the original path (2 x hops x leaves collectives), kept as
              the parity oracle.  Identity compression always uses it (raw
              float leaves move; there is nothing to bucket).
@@ -332,22 +332,22 @@ class DecentralizedTrainer:
     def _partial_manual(self) -> bool:
         """Does the gossip shard_map leave the model axis auto (GSPMD)?
 
-        Only the per-leaf wire path on JAX >= 0.6: 0.4.x rejects ppermute
-        under partial-manual, and the bucketed path's cross-dim reshapes
-        must not gather the auto model axis, so both run FULL-manual
-        (identity compression always takes the per-leaf path)."""
+        Only on the per-leaf wire path: the bucketed path's cross-dim
+        reshapes must not gather the auto model axis, so it runs
+        FULL-manual (identity compression always takes the per-leaf
+        path)."""
         use_bucket = (self.tcfg.wire_mode == "bucketed"
                       and not isinstance(self.compressor, Identity))
-        return compat.HAS_SHARD_MAP and not use_bucket
+        return not use_bucket
 
     def _quant_block(self, diff_shape) -> int:
         """Quantization block size, optionally aligned to the model shard.
 
         ``diff_shape`` is the leaf as the quantizer sees it: the full
         per-node leaf under partial-manual shard_map (model axis auto), the
-        model-LOCAL slice under full-manual (0.4.x always; bucketed on any
-        JAX) — in the latter case the slice is already shard-aligned, so
-        no further division by tp_ways applies."""
+        model-LOCAL slice under full-manual (the bucketed path) — in the
+        latter case the slice is already shard-aligned, so no further
+        division by tp_ways applies."""
         tcfg = self.tcfg
         # never pad a row past its own width: a (model-local) last dim
         # below the block size would otherwise ship a full padded block
@@ -398,7 +398,7 @@ class DecentralizedTrainer:
         # The bucketed path concatenates and reshapes leaves across their
         # trailing dims, which under partial-manual shard_map would force
         # GSPMD to gather the auto (model) axis — so it always runs
-        # FULL-manual, like every mode does on 0.4.x (see below).
+        # FULL-manual (see below).
         partial_manual = self._partial_manual
         # (1 + n_hops, T, n): row 0 the exact-stochastic self weight, then
         # one row per hop — receiver-indexed, per schedule round.
@@ -426,9 +426,7 @@ class DecentralizedTrainer:
             # leaves have a leading local node dim of size 1; Hw leaves an
             # extra T dim ((1, T, ...)) when the plan is time-varying.
             # node_id is a P(naxes)-sharded iota: its local shard holds this
-            # node's index (axis_index lowers to a PartitionId instruction
-            # that jax 0.4.x's SPMD partitioner rejects under partial-manual
-            # shard_map, so the index arrives as data instead).
+            # node's index.
             idx = node_id[0]
             t = jnp.asarray(step_k, jnp.int32) % T
             wmat = jnp.asarray(wmat_np)[:, :, idx]       # (1 + hops, T)
@@ -497,15 +495,13 @@ class DecentralizedTrainer:
             unf = lambda ls: jax.tree_util.tree_unflatten(treedef, ls)
             return unf(nX), unf(nD), unf(nH), unf(nHw)
 
-        # Modern JAX per-leaf mode: partial-manual shard_map — specs mention
-        # ONLY the manual (node) axes, the model-axis sharding of trailing
-        # dims stays under GSPMD (auto axes).  FULL-manual everywhere else:
-        # on 0.4.x the SPMD partitioner rejects ppermute under
-        # partial-manual (hard CHECK), and the bucketed wire path reshapes
-        # across trailing dims, which must not gather the model axis —
-        # every mesh axis goes manual, specs carry the per-leaf model
-        # placement (param_specs), and each model shard quantizes/ppermutes
-        # its local slice independently.
+        # Per-leaf mode: partial-manual shard_map — specs mention ONLY the
+        # manual (node) axes, the model-axis sharding of trailing dims
+        # stays under GSPMD (auto axes).  The bucketed wire path reshapes
+        # across trailing dims, which must not gather the model axis, so
+        # it runs FULL-manual: every mesh axis goes manual, specs carry the
+        # per-leaf model placement (param_specs), and each model shard
+        # quantizes/ppermutes its local slice independently.
         key_data = jax.random.key_data(key)
         node_ids = jnp.arange(tcfg.n_nodes, dtype=jnp.int32)
         if partial_manual:
@@ -524,10 +520,9 @@ class DecentralizedTrainer:
                                          dtype=jnp.int32),)
             else:
                 # no model sharding -> no shard-id key folding: fold_in(k,
-                # 0) != k, and on >= 0.6 the per-leaf mode runs partial-
-                # manual WITHOUT the fold — skipping it keeps the two wire
-                # modes bit-for-bit equal on single-model-shard meshes
-                # under any JAX
+                # 0) != k, and the per-leaf mode runs partial-manual
+                # WITHOUT the fold — skipping it keeps the two wire modes
+                # bit-for-bit equal on single-model-shard meshes
                 extra_in, extra_args = (), ()
         hw_specs = specs if T == 1 else self._hw_specs(specs)
         shmapped = compat.shard_map(

@@ -14,22 +14,20 @@ sender 0 = self plus one sender per hop:
              and scales (2 x hops x leaves collectives).  Kept as the
              parity oracle; bit-for-bit equal to bucketed whenever both
              run under the same shard_map manualness (see
-             repro.optim.decentralized's module docstring for the one
-             >= 0.6 model-sharded exception).
+             repro.optim.decentralized's module docstring for the
+             model-sharded exception).
 
 All functions run INSIDE shard_map: leaves carry a leading local node dim
 of 1, ``pp(x, pairs)`` is the axis-bound ppermute closure, and ``wmat`` is
 the (1 + hops, T) receiver-indexed weight table (row 0 = self weight).
 
-Bitwise caveat: both modes mix through the same sender-axis dot
-(kernels.ref.weighted_mix_ref — a tensordot ON PURPOSE, because an
-unrolled multiply-add chain gets FMA-contracted shape-dependently by
-XLA's CPU backend), so codes, scales, and qself are exact and the mixes
-agree bit for bit on lane-aligned leaves (every model config in
-repro.configs).  A leaf whose last dim is not a multiple of the f32
-vector width can still differ in the LAST ULP of a T > 1 mix — the dot's
-unaligned-tail codegen varies per operand shape (tests/test_bucket.py
-pins down both behaviors).
+Bitwise caveat: both modes mix through the same fixed-order sum of
+exact partial products (kernels.ref.mix_sum, which the Pallas mix kernel
+runs too), so codes, scales, qself and the mixes agree bit for bit on
+any backend.  Narrow (bf16) leaves agree only in a step compiled without
+XLA's excess precision (repro.api.STEP_COMPILER_OPTIONS): the bucketed
+path rounds diffs and payloads to the leaf dtype around its kernels,
+while per_leaf fuses them and would otherwise keep them in f32.
 """
 from __future__ import annotations
 
@@ -88,13 +86,7 @@ class WireExchange:
                              [d.dtype for d in diffs])
         self._record(hop_pairs, bytes_per_hop=layout.wire_bits // 8,
                      collectives_per_hop=2)
-        xbs, us = [], []
-        for d, k, sl in zip(diffs, keys, layout.slots):
-            xb = kops.blockwise_lastdim(d, block=sl.block)
-            xbs.append(xb)
-            # same key, same shape as the per-leaf quantizer's draw
-            us.append(jax.random.uniform(k, xb.shape, jnp.float32))
-        cw, sw = bucket.pack_to_wire(layout, xbs, us,
+        cw, sw = bucket.pack_to_wire(layout, diffs, keys,
                                      use_pallas=self.use_pallas)
         # the ONLY communication: 2 buffers x hops, leaf-count independent
         wires = [(cw, sw)] + [(pp(cw, pr), pp(sw, pr)) for pr in hop_pairs]
@@ -137,9 +129,15 @@ class WireExchange:
             q_self = kops.qinf_dequantize_lastdim(
                 codes, scales.astype(jnp.float32), d.shape, d.dtype,
                 block=blk)
-            qstack = jnp.stack([q_self] + recvs)        # (1 + hops, ...)
+            # materialize the (1 + hops, ...) payloads in the leaf's dtype
+            # and shape: left free, XLA moves the quantizer's block-to-leaf
+            # relayout past the mix's multiplies and copies every partial
+            # product out in f32 (4.6 GB more per chip for four qwen3-1.7b
+            # nodes compiled for a v5e, past its memory)
+            qstack = jax.lax.optimization_barrier(
+                jnp.stack([q_self] + recvs))
             wq.append(kref.weighted_mix_ref(
-                jnp.asarray(wmat).T, qstack).astype(d.dtype))
+                jnp.asarray(wmat).T, qstack, d.dtype).astype(d.dtype))
             qs.append(q_self)
         return wq, qs
 
@@ -153,7 +151,6 @@ class WireExchange:
         wq: List = []
         for d in diffs:
             recvs = [pp(d, pr) for pr in hop_pairs]
-            qstack = jnp.stack([d] + recvs)
             wq.append(kref.weighted_mix_ref(
-                jnp.asarray(wmat).T, qstack).astype(d.dtype))
+                jnp.asarray(wmat).T, [d] + recvs, d.dtype).astype(d.dtype))
         return wq, list(diffs)

@@ -16,11 +16,31 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
+# the hand-built reference, placed like TrainerRunner places it: state and
+# batch under NamedShardings from state_specs / batch_specs
+_PLACED = """
+from jax.sharding import NamedSharding, PartitionSpec as P
+
+
+def placed_step(tr, mesh, batch):
+    ns = lambda t: jax.tree_util.tree_map(
+        lambda s: NamedSharding(mesh, s), t,
+        is_leaf=lambda x: isinstance(x, P))
+    st = ns(tr.state_specs(("data",)))
+    bt = ns(tr.batch_specs(batch, ("data",)))
+    init = jax.jit(tr.init_state, out_shardings=st)
+    step = jax.jit(tr.train_step, in_shardings=(st, bt),
+                   out_shardings=(st, None))
+    return init, lambda s, b: step(s, jax.device_put(b, bt))
+"""
+
+
 def _run_sub(code: str, devices: int = 8):
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
-    return subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
+    code = _PLACED + textwrap.dedent(code)
+    return subprocess.run([sys.executable, "-c", code],
                           capture_output=True, text=True, env=env,
                           timeout=560)
 
@@ -45,9 +65,9 @@ class TestSpecTrainerParity:
                 tr = DecentralizedTrainer(cfg, TrainerConfig(
                     n_nodes=n, backend="neighbor", compressor="qinf",
                     bits=2, eta=0.1, **sched_kw), mesh=mesh)
-                s_ref = tr.init_state(jax.random.key(0))
                 with compat.set_mesh(mesh):
-                    step = jax.jit(tr.train_step)
+                    init, step = placed_step(tr, mesh, data.batch_at(0))
+                    s_ref = init(jax.random.key(0))
                     for t in range(3):
                         s_ref, _ = step(s_ref, data.batch_at(t))
 
@@ -101,9 +121,9 @@ class TestSpecTrainerParity:
         data = DecentralizedBatches(4, 2, 16, cfg.vocab)
         tr = DecentralizedTrainer(cfg, TrainerConfig(
             n_nodes=4, compressor="qinf", bits=2, eta=0.1), mesh=mesh)
-        s_ref = tr.init_state(jax.random.key(0))
         with compat.set_mesh(mesh):
-            step = jax.jit(tr.train_step)
+            init, step = placed_step(tr, mesh, data.batch_at(0))
+            s_ref = init(jax.random.key(0))
             for t in range(3):
                 s_ref, _ = step(s_ref, data.batch_at(t))
 

@@ -210,8 +210,7 @@ class TestSmallMeshLowering:
             tr, jax.tree_util.tree_leaves(state.plead.X))
         predicted = (len(tr.plan.hops) * per_edge / 8
                      / model_axis_size(mesh))
-        if not compat.HAS_SHARD_MAP:        # full-manual accounting path
-            assert u8_bytes == predicted, (u8_bytes, predicted)
+        assert u8_bytes == predicted, (u8_bytes, predicted)
         print("RING_OK", len(u8), u8_bytes, other)
         """
         r = _run_sub(code)
@@ -310,7 +309,7 @@ class TestNeighborBackend:
             r.stdout + r.stderr[-2000:]
 
     def test_model_replicated_leaves_stay_consistent_under_qinf(self):
-        """Regression (full-manual 0.4.x path): stochastic-rounding keys
+        """Regression (full-manual path): stochastic-rounding keys
         must be decorrelated across model shards ONLY for model-sharded
         leaves — replicated leaves (norms, biases) drawing different
         randomness per shard silently diverge, since check_rep is off."""
@@ -350,10 +349,10 @@ class TestNeighborBackend:
         """wire_mode='bucketed' must reproduce the per-leaf path EXACTLY —
         same codes, same scales, same mixes — for a static ring and a
         T > 1 schedule.  Exactness requires both modes to run the same
-        shard_map manualness: always true on 0.4.x; on >= 0.6 the (4, 2)
-        mesh runs per-leaf partial-manual vs bucketed full-manual (noise
-        drawn on different shard geometries — equal in distribution only),
-        so the model-sharded case is asserted on 0.4.x alone."""
+        shard_map manualness, which holds on a model-unsharded mesh: a
+        (4, 2) mesh runs per-leaf partial-manual vs bucketed full-manual
+        (noise drawn on different shard geometries — equal in distribution
+        only)."""
         code = """
         import jax, jax.numpy as jnp, numpy as np
         from repro import compat, configs
@@ -362,8 +361,6 @@ class TestNeighborBackend:
 
         cfg = configs.get("qwen3-1.7b").reduced(n_layers=1, d_model=64)
         meshes = [((8, 1), 8)]
-        if not compat.HAS_SHARD_MAP:
-            meshes.append(((4, 2), 4))
         for meshshape, n in meshes:
             mesh = compat.make_mesh(meshshape, ("data", "model"))
             data = DecentralizedBatches(n, 2, 16, cfg.vocab)
@@ -546,9 +543,11 @@ class TestKernelRooflineGate:
             # (a)+(b) roofline layout == exact payload accounting
             layout, model = obs.trainer_wire_layout(tr, leaves)
             assert model * layout.wire_bits == per_edge, meshshape
-            k = obs.kernel_roofline(layout, hops=hops)
+            v5e = roofline.PEAKS["TPU v5 lite"]
+            k = obs.kernel_roofline(layout, peaks=v5e, hops=hops)
             assert k["wire"]["bytes_per_hop"] * 8 * model == per_edge
-            sr = obs.step_roofline(layout, hops=hops, measured_step_s=1.0)
+            sr = obs.step_roofline(layout, peaks=v5e, hops=hops,
+                                   measured_step_s=1.0)
             assert sr["wire_bytes_per_hop"] * 8 == layout.wire_bits
             assert sr["predicted_step_s"] == (
                 sr["predicted_kernel_s"] + sr["predicted_wire_s"])

@@ -107,3 +107,46 @@ def test_lastdim_quantize_any_block(block):
     bound = jnp.max(jnp.abs(pad), axis=-1, keepdims=True) / 2.0
     outp = jnp.zeros((3, nb * block)).at[:, :1408].set(out).reshape(3, nb, block)
     assert (jnp.abs(outp - pad) <= bound + 1e-5).all()
+
+
+def test_split_hi_lo_exact_parts():
+    """hi + lo == x bit for bit, each part has <= 12 significant bits, and
+    the product of two parts is exact (checked in f64)."""
+    x = jnp.concatenate([
+        jax.random.normal(jax.random.key(0), (4096,), jnp.float32) * 3,
+        jnp.asarray([0.0, -0.0, 1 / 3, -2 / 3, 1.0, 1e-30, -7.5e20],
+                    jnp.float32)])
+    hi, lo = kref.split_hi_lo(x)
+    np.testing.assert_array_equal(np.asarray(hi + lo), np.asarray(x))
+    for part in (hi, lo):
+        m, _ = np.frexp(np.asarray(part, np.float64))
+        assert np.all(m * 2 ** 12 == np.round(m * 2 ** 12))
+    y = np.asarray(jax.random.normal(jax.random.key(1), x.shape, jnp.float32))
+    yh, yl = (np.asarray(p) for p in kref.split_hi_lo(jnp.asarray(y)))
+    for a in (hi, lo):
+        for b in (yh, yl):
+            a64 = np.asarray(a, np.float64)
+            np.testing.assert_array_equal(
+                (np.asarray(a) * b).astype(np.float64), a64 * b)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_weighted_mix_same_bits_in_any_view(dtype):
+    """The mix of a (S, R, B) stack equals, bit for bit, the mix of its
+    (S, R * B) view and of its per-sender list under jit — the bucketed
+    and per-leaf wire paths mix differently shaped views of one payload —
+    and stays within f32 rounding of the f64 sum."""
+    S, T = 3, 2
+    q = (jax.random.normal(jax.random.key(0), (S, 24, 104),
+                           jnp.float32) * 2).astype(
+        dtype).astype(jnp.float32)
+    w = jnp.asarray([[1 / 3, 1 / 3, 1 / 3], [0.4, 0.1, 0.5]], jnp.float32)
+    mix = jax.jit(lambda w, q: kref.weighted_mix_ref(w, q, dtype))
+    a = mix(w, q)
+    b = mix(w, q.reshape(S, -1)).reshape(a.shape)
+    c = jax.jit(lambda w, *qs: kref.weighted_mix_ref(w, qs, dtype))(w, *q)
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(c))
+    want = np.tensordot(np.asarray(w, np.float64), np.asarray(q, np.float64),
+                        axes=(1, 0))
+    np.testing.assert_allclose(np.asarray(a), want, rtol=1e-6, atol=1e-6)
