@@ -1,0 +1,13 @@
+"""Quantize+pack kernel: the bytes the algorithm needs per step
+(bench/counts.py) at the chip's HBM peak, over the kernel's device time
+per step (%)."""
+from bench import counts, peaks
+
+
+def read(ctx):
+    tr = ctx.cell["traffic_params"]
+    s = ctx.layer_s_per_step("quant_pack")
+    if tr["compressor"] != "qinf" or s is None:
+        return None
+    need = counts.quant_pack_bytes(ctx.cell["cfg"], tr["bits"], tr["block"])
+    return 100.0 * need / peaks.peaks_for(ctx.device_kind).hbm_bw / s
