@@ -938,6 +938,7 @@ class TrainerRunner(Runner):
         self._jit_init = None
         self._compiled_step = None
         self._batch_sh = None
+        self._dispatched = 0            # steps dispatched, for the trace
 
     # trainer passthroughs ---------------------------------------------------
     @property
@@ -1025,14 +1026,21 @@ class TrainerRunner(Runner):
 
     def step(self, state, batch):
         """One train step.  On a mesh ``state`` is donated: use the
-        returned state, not the one passed in."""
+        returned state, not the one passed in.  In a profile the call is
+        the span ``obs.STEP`` numbered by this runner's count of
+        dispatched steps, with the batch's placement and the executable
+        call as its children."""
         self._require_mesh()
-        fn = self._compiled_step or self._step_fn(batch)
-        if self.mesh is not None:
-            # each node's batch to its own devices, wherever it was made
-            batch = jax.device_put(batch, self._batch_sh)
-        with self._on_mesh():
-            return fn(state, batch)
+        n = self._dispatched
+        self._dispatched += 1
+        with obs.annotate(obs.STEP, step_num=n):
+            fn = self._compiled_step or self._step_fn(batch)
+            if self.mesh is not None:
+                # each node's batch to its own devices, wherever it was made
+                with obs.annotate(obs.STEP_PLACE):
+                    batch = jax.device_put(batch, self._batch_sh)
+            with self._on_mesh(), obs.annotate(obs.STEP_CALL):
+                return fn(state, batch)
 
     def run(self, *, num_steps: Optional[int] = None, data=None, state=None,
             key=None, callback=None, log_every: int = 0):
@@ -1057,12 +1065,10 @@ class TrainerRunner(Runner):
                     logs.append(callback(state, metrics, t))
             tsp.ready(state)
         bits = self.bits_per_step(state)
-        mean_step = tsp.elapsed_s / num_steps if num_steps else 0.0
         self.last_report = obs.build_report(
             name=sp.name if sp else "trainer", engine="sharded",
             steps=num_steps, total_s=tsp.elapsed_s, bits_per_step=bits,
             scope="node", meters=meters,
-            roofline=self._wire_roofline(state, mean_step),
             extra={"backend": self.trainer.tcfg.backend,
                    "wire_mode": self.trainer.tcfg.wire_mode})
         return state, logs
@@ -1091,23 +1097,6 @@ class TrainerRunner(Runner):
         Wn = np.abs(np.asarray(W))
         directed = int((Wn > 1e-12).sum() - (np.diag(Wn) > 1e-12).sum())
         return per_edge * directed / Wn.shape[0]
-
-    def _wire_roofline(self, state, mean_step_s: float) -> dict:
-        """Kernel/wire roofline for the bucketed gossip path (empty dict
-        when this trainer has no bucket layout to price, or the device has
-        no entry in ``obs.roofline.PEAKS``)."""
-        tr = self.trainer
-        from repro.core.compression import Identity
-        from repro.obs.roofline import peaks_for
-        peaks = peaks_for(jax.devices()[0].device_kind)
-        if tr.plan is None or isinstance(tr.compressor, Identity) \
-                or tr.tcfg.wire_mode != "bucketed" or peaks is None:
-            return {}
-        layout, _model = obs.trainer_wire_layout(
-            tr, jax.tree_util.tree_leaves(state.plead.X))
-        return obs.step_roofline(layout, peaks=peaks,
-                                 hops=len(tr.plan.hops),
-                                 measured_step_s=mean_step_s or None)
 
     def default_data(self):
         if self.spec is None or self.spec.model is None:

@@ -20,6 +20,11 @@ a leaf whose (model-local) last dim is narrower than the configured block
 quantizes at its own width and no padded block ever ships — the buffer
 holds exactly the bytes the per-leaf path would have moved, concatenated.
 
+The jnp work runs under the train step's named scopes (repro.obs):
+``prox_lead/noise`` for the rounding-noise draws, ``prox_lead/wire_layout``
+for everything else, the kernel calls included (they keep their own
+``jit(qinf_*)`` names inside it).
+
 Everything here is static Python over leaf shapes; the jnp work (blocking,
 fused quantize+pack, fused unpack+dequant+mix) dispatches through
 :mod:`repro.kernels.ops`, one kernel call per leaf: a call over a whole
@@ -37,6 +42,7 @@ import numpy as np
 
 from repro.kernels import ops as kops
 from repro.kernels.quantize import packed_width
+from repro.obs.trace import NOISE, WIRE_LAYOUT
 
 
 def default_quant_block(shape: Sequence[int], block: int = 256) -> int:
@@ -192,15 +198,19 @@ def pack_to_wire(layout: BucketLayout, diffs: Sequence[jax.Array],
             d = diffs[i]
             # in the leaf's dtype: the kernel upcasts in VMEM, and an f32
             # copy of a large leaf is what a chip runs short of
-            xb = kops.blockwise_lastdim(d, block=g.block, dtype=d.dtype)
-            u = jax.random.uniform(keys[i], xb.shape, jnp.float32)
-            packed, scales = kops.qinf_quantize_pack(
-                xb.reshape(-1, g.block), u.reshape(-1, g.block),
-                bits=layout.bits, block=g.block, use_pallas=use_pallas)
-            codes_segs.append(packed.reshape(-1))
-            scales_segs.append(_to_bytes(
-                scales.astype(_scales_dtype(layout))))
-    return jnp.concatenate(codes_segs), jnp.concatenate(scales_segs)
+            with jax.named_scope(WIRE_LAYOUT):
+                xb = kops.blockwise_lastdim(d, block=g.block, dtype=d.dtype)
+            with jax.named_scope(NOISE):
+                u = jax.random.uniform(keys[i], xb.shape, jnp.float32)
+            with jax.named_scope(WIRE_LAYOUT):
+                packed, scales = kops.qinf_quantize_pack(
+                    xb.reshape(-1, g.block), u.reshape(-1, g.block),
+                    bits=layout.bits, block=g.block, use_pallas=use_pallas)
+                codes_segs.append(packed.reshape(-1))
+                scales_segs.append(_to_bytes(
+                    scales.astype(_scales_dtype(layout))))
+    with jax.named_scope(WIRE_LAYOUT):
+        return jnp.concatenate(codes_segs), jnp.concatenate(scales_segs)
 
 
 def rows_to_leaf(slot: LeafSlot, rows: jax.Array,
@@ -227,21 +237,23 @@ def mix_from_wire(layout: BucketLayout, wires: Sequence[Tuple[jax.Array,
     sb = layout.scale_bytes
     wq: list = [None] * len(layout.slots)
     qs: list = [None] * len(layout.slots)
-    for g in layout.groups:
-        pw = g.packed_width
-        for i in g.leaf_indices:                # one kernel call per leaf
-            sl = layout.slots[i]
-            c0 = g.codes_offset + sl.row_offset * pw
-            s0 = g.scales_offset + sl.row_offset * sb
-            pstack = jnp.stack([c[c0: c0 + sl.rows * pw].reshape(sl.rows, pw)
-                                for c, _ in wires])
-            sstack = jnp.stack([
-                _from_bytes(s[s0: s0 + sl.rows * sb], sl.rows,
-                            sdtype).astype(jnp.float32)[:, None]
-                for _, s in wires])
-            mix, qself = kops.qinf_unpack_dequant_mix(
-                pstack, sstack, w, bits=layout.bits, block=g.block,
-                out_dtype=g.dtype, use_pallas=use_pallas)
-            wq[i] = rows_to_leaf(sl, mix, lead=(T,))
-            qs[i] = rows_to_leaf(sl, qself)
+    with jax.named_scope(WIRE_LAYOUT):
+        for g in layout.groups:
+            pw = g.packed_width
+            for i in g.leaf_indices:            # one kernel call per leaf
+                sl = layout.slots[i]
+                c0 = g.codes_offset + sl.row_offset * pw
+                s0 = g.scales_offset + sl.row_offset * sb
+                pstack = jnp.stack([
+                    c[c0: c0 + sl.rows * pw].reshape(sl.rows, pw)
+                    for c, _ in wires])
+                sstack = jnp.stack([
+                    _from_bytes(s[s0: s0 + sl.rows * sb], sl.rows,
+                                sdtype).astype(jnp.float32)[:, None]
+                    for _, s in wires])
+                mix, qself = kops.qinf_unpack_dequant_mix(
+                    pstack, sstack, w, bits=layout.bits, block=g.block,
+                    out_dtype=g.dtype, use_pallas=use_pallas)
+                wq[i] = rows_to_leaf(sl, mix, lead=(T,))
+                qs[i] = rows_to_leaf(sl, qself)
     return wq, qs

@@ -19,10 +19,14 @@ def use_compile_cache() -> str:
 
     ``JAX_COMPILATION_CACHE_DIR``, when set, is left for JAX to read and
     no other directory is set.  Otherwise the cache lives at
-    :data:`CACHE_DIR`."""
+    :data:`CACHE_DIR`.  Either way an entry's key covers the program's
+    metadata: a profile attributes device time by the op names the train
+    step's named scopes give (``repro.obs``), and JAX's default key leaves
+    them out, so a hit on a build with other names would bring those."""
+    import jax
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env
-    import jax
     jax.config.update("jax_compilation_cache_dir", str(CACHE_DIR))
     return str(CACHE_DIR)

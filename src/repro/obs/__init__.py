@@ -1,37 +1,39 @@
-"""repro.obs — step-time telemetry, wire meters, and kernel rooflines.
+"""repro.obs — step-time telemetry, wire meters, and the step's names.
 
 Three layers (ISSUE 6 / ROADMAP "Roofline-gated perf CI"):
 
 * meters + spans (:mod:`repro.obs.meters`, :mod:`repro.obs.trace`) —
   a process-local name -> number registry fed by the exact accounting
   that already exists (``netsim.metrics`` bits, ``core.bucket`` payload
-  bytes, trace counts) plus ``block_until_ready``-correct wall-clock
+  bytes, trace counts, compile counts and seconds from
+  ``jax.monitoring``) plus ``block_until_ready``-correct wall-clock
   spans.  Instrumented code (``WireExchange``, ``netsim.simulate``, the
   Runner adapters) records into the *ambient* registry installed with
   :func:`using_meters`; with no registry installed every hook is a no-op,
   so the telemetry costs nothing on the hot path and nothing at trace
   time.
 
+* names in the profiler's trace (:mod:`repro.obs.trace`) — the named
+  scopes of the train step's parts (:data:`SCOPES`), which every device
+  op carries in its HLO ``op_name``, and the host spans of
+  ``TrainerRunner.step`` (:data:`STEP` and its children), written through
+  :func:`annotate` on the profiler's clock.  ``bench/`` reads both.
+
 * structured reports (:mod:`repro.obs.report`) — every ``Runner.run``
   emits a :class:`RunReport` (JSON-serializable) with the measured step
-  time and the exact bits on the wire, stored on the runner as
-  ``last_report``.
-
-* roofline comparison (:mod:`repro.obs.roofline_gate`) — analytical
-  HBM/link rooflines for the fused wire kernels, derived from the exact
-  byte counts in :class:`repro.core.bucket.BucketLayout`, reported as
-  measured-vs-predicted utilization.  The CI gate that closes the loop
-  lives in ``tools/perf_gate.py``.
+  time, the exact bits on the wire and the run's meters, stored on the
+  runner as ``last_report``.
 """
 from repro.obs.meters import Meters, current_meters, env_info, using_meters
 from repro.obs.report import RunReport, build_report, wire_breakdown
-from repro.obs.roofline_gate import (kernel_roofline, step_roofline,
-                                     trainer_wire_layout)
-from repro.obs.trace import annotate, span
+from repro.obs.trace import (CONSENSUS, EXCHANGE, FWD_BWD, NOISE, SCOPES,
+                             STEP, STEP_CALL, STEP_PLACE, UPDATE,
+                             WIRE_LAYOUT, annotate, span)
 
 __all__ = [
     "Meters", "current_meters", "using_meters", "env_info",
     "span", "annotate",
+    "FWD_BWD", "NOISE", "WIRE_LAYOUT", "EXCHANGE", "UPDATE", "CONSENSUS",
+    "SCOPES", "STEP", "STEP_PLACE", "STEP_CALL",
     "RunReport", "build_report", "wire_breakdown",
-    "kernel_roofline", "step_roofline", "trainer_wire_layout",
 ]

@@ -21,7 +21,23 @@ returns ``None``), so un-instrumented callers pay nothing.
 Naming convention (slash-separated namespaces, units as suffixes):
 ``time/<span>_s``, ``time/<span>_n``, ``wire/bytes_per_hop``,
 ``wire/hops``, ``wire/collectives_per_step``, ``wire/traces``,
-``netsim/bits_per_edge_per_round``.
+``netsim/bits_per_edge_per_round``, and the compile counters below.
+
+Compile counters come from ``jax.monitoring``: the first
+:func:`using_meters` registers one duration listener and one event
+listener for the process, which forward into the ambient registry and do
+nothing when none is installed:
+
+* ``compile/backend_s``, ``compile/n`` — every XLA compile
+  (``/jax/core/compile/backend_compile_duration``).  That event wraps the
+  persistent-cache lookup, so a cache hit's read is counted there and
+  nowhere else;
+* ``compile/cache_hits``, ``compile/cache_misses`` — the persistent
+  compile cache;
+* ``compile/step_lower_s``, ``compile/step_n`` — tracing plus MLIR
+  lowering of the train step itself (:data:`STEP_FUN`), once per
+  lowering.  Jits nested in the step fire trace events of their own
+  inside the step's; they are left out, so no second counts twice.
 """
 from __future__ import annotations
 
@@ -86,6 +102,7 @@ def current_meters() -> Optional[Meters]:
 @contextlib.contextmanager
 def using_meters(meters: Meters) -> Iterator[Meters]:
     """Install ``meters`` as the ambient registry for the with-block."""
+    _listen_to_compiles()
     with _STACK_LOCK:
         _STACK.append(meters)
     try:
@@ -93,6 +110,55 @@ def using_meters(meters: Meters) -> Iterator[Meters]:
     finally:
         with _STACK_LOCK:
             _STACK.remove(meters)
+
+
+# --------------------------------------------------------------------------
+# Compile counters (see module docstring)
+# --------------------------------------------------------------------------
+
+# The function TrainerRunner jits: DecentralizedTrainer.train_step.
+STEP_FUN = "train_step"
+
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+# (event, fun_name) of the train step's own tracing and lowering
+_STEP_LOWERING = {(_TRACE, STEP_FUN), (_LOWER, f"jit({STEP_FUN})")}
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "compile/cache_hits",
+                 "/jax/compilation_cache/cache_misses":
+                     "compile/cache_misses"}
+_listening = False
+
+
+def _on_duration(event: str, duration_s: float, **kwargs) -> None:
+    m = current_meters()
+    if m is None:
+        return
+    if event == _BACKEND_COMPILE:
+        m.inc("compile/backend_s", duration_s)
+        m.inc("compile/n")
+    elif (event, kwargs.get("fun_name")) in _STEP_LOWERING:
+        m.inc("compile/step_lower_s", duration_s)
+        if event == _TRACE:
+            m.inc("compile/step_n")
+
+
+def _on_event(event: str, **kwargs) -> None:
+    m = current_meters()
+    if m is not None and event in _CACHE_EVENTS:
+        m.inc(_CACHE_EVENTS[event])
+
+
+def _listen_to_compiles() -> None:
+    """Register the compile listeners, once per process."""
+    global _listening
+    with _STACK_LOCK:
+        if _listening:
+            return
+        _listening = True
+    from jax import monitoring
+    monitoring.register_event_duration_secs_listener(_on_duration)
+    monitoring.register_event_listener(_on_event)
 
 
 # --------------------------------------------------------------------------

@@ -20,10 +20,8 @@ wire       the exact accounting: ``bits_per_step`` / ``bits_total`` from
            what one "bits_per_step" covers: ``node`` (one sender, the
            sharded/dense convention) or ``system`` (all edges, the
            netsim trajectory convention).
-meters     raw snapshot of the run's :class:`~repro.obs.meters.Meters`.
-roofline   :func:`repro.obs.roofline_gate.step_roofline` output when the
-           engine has a bucket layout (sharded trainer) and the device is
-           in ``obs.roofline.PEAKS``, else empty.
+meters     raw snapshot of the run's :class:`~repro.obs.meters.Meters`,
+           the compile counters (``compile/...``) among them.
 extra      engine-specific fields (algo name, final consensus, ...).
 =========  ================================================================
 """
@@ -47,7 +45,6 @@ class RunReport:
     timing: Dict[str, float]
     wire: Dict[str, Any]
     meters: Dict[str, float]
-    roofline: Dict[str, Any]
     extra: Dict[str, Any]
 
     def to_dict(self) -> Dict[str, Any]:
@@ -91,7 +88,6 @@ def build_report(*, name: str, engine: str, steps: int, total_s: float,
                  bits_total: Optional[float] = None,
                  scope: str = "node",
                  meters: Optional[Meters] = None,
-                 roofline: Optional[Dict] = None,
                  extra: Optional[Dict] = None) -> RunReport:
     """Assemble a RunReport from a run's measured total seconds and exact
     bit accounting; derived timing fields and the env stamp are filled
@@ -111,5 +107,5 @@ def build_report(*, name: str, engine: str, steps: int, total_s: float,
         name=name, engine=engine, steps=int(steps), env=env,
         timing=wire_breakdown(total_s, steps, bits_per_step,
                               peaks_for(env["device_kind"])),
-        wire=wire, meters=m, roofline=dict(roofline or {}),
+        wire=wire, meters=m,
         extra=dict(extra or {}))

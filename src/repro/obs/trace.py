@@ -17,9 +17,19 @@ On exit the elapsed seconds accumulate into the ambient
 occurrence counter ``time/<name>_n`` — counter semantics, so nested loops
 of short spans sum.
 
-:func:`annotate` wraps ``jax.profiler.TraceAnnotation`` when the profiler
-is available (names show up in TensorBoard / perfetto traces) and
-degrades to a no-op context manager otherwise.
+:func:`annotate` marks a host region in the profiler's trace (a
+``jax.profiler.TraceAnnotation``, or a ``StepTraceAnnotation`` when given
+a ``step_num``); with no profiler session active it records nothing.
+``TrainerRunner.step`` marks each call as :data:`STEP` with the runner's
+count of dispatched steps, and its two parts as :data:`STEP_PLACE` and
+:data:`STEP_CALL`.  The profiler writes these spans into the same trace as
+the device ops, on the same clock.
+
+The names of the train step's parts on the device live here too: the
+step wraps each part in ``jax.named_scope(<scope>)``, so every op the
+part makes carries the scope in its HLO ``op_name`` and a profile can
+attribute the op.  Scopes are metadata: the compiled program is the same.
+No scope nests inside another, so an op lies under at most one.
 """
 from __future__ import annotations
 
@@ -60,9 +70,24 @@ def span(name: str, meters: Optional[Meters] = None):
             m.inc(f"time/{name}_n", 1)
 
 
-def annotate(name: str):
-    """Profiler trace annotation when available, nullcontext otherwise."""
-    try:
+# Named scopes of the train step's parts (see module docstring).
+FWD_BWD = "fwd_bwd"                    # loss_and_grad
+NOISE = "prox_lead/noise"              # key folds and rounding-noise draws
+WIRE_LAYOUT = "prox_lead/wire_layout"  # leaf <-> wire-buffer relayouts
+EXCHANGE = "prox_lead/exchange"        # ppermutes; the identity path's mix
+UPDATE = "prox_lead/update"            # Prox-LEAD lines 6-10, elementwise
+CONSENSUS = "consensus"                # the consensus metric
+SCOPES = (FWD_BWD, NOISE, WIRE_LAYOUT, EXCHANGE, UPDATE, CONSENSUS)
+
+# Host spans of TrainerRunner.step.
+STEP = "repro.step"
+STEP_PLACE = "repro.step.place"        # the batch's device_put
+STEP_CALL = "repro.step.call"          # the executable call
+
+
+def annotate(name: str, step_num: Optional[int] = None):
+    """Mark a host region ``name`` in the profiler's trace; with
+    ``step_num``, as one step of a training loop."""
+    if step_num is None:
         return jax.profiler.TraceAnnotation(name)
-    except Exception:
-        return contextlib.nullcontext()
+    return jax.profiler.StepTraceAnnotation(name, step_num=step_num)

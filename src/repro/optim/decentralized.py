@@ -62,7 +62,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
+from repro import compat, obs
 from repro.core import topology as topo_mod
 from repro.core.comm import CommState, DenseMixer, comm, init_comm_state
 from repro.core.compression import Compressor, Identity, make_compressor
@@ -290,7 +290,8 @@ class DecentralizedTrainer:
             losses, ces = jax.vmap(self._node_loss)(Xs, batch)
             return jnp.sum(losses), jnp.mean(ces)
 
-        (tot, ce), G = jax.value_and_grad(total, has_aux=True)(X)
+        with jax.named_scope(obs.FWD_BWD):
+            (tot, ce), G = jax.value_and_grad(total, has_aux=True)(X)
         return ce, G
 
     # ------------------------------------------------------------------ step
@@ -298,19 +299,25 @@ class DecentralizedTrainer:
         ce, G = self.loss_and_grad(state.plead.X, batch)
         precond = state.precond
         if self.tcfg.precondition == "adam":
-            G, precond = self._adam_precondition(G, precond, state.step)
-        key = jax.random.fold_in(jax.random.key(self.tcfg.seed), state.step)
+            with jax.named_scope(obs.UPDATE):
+                G, precond = self._adam_precondition(G, precond, state.step)
+        with jax.named_scope(obs.NOISE):
+            key = jax.random.fold_in(jax.random.key(self.tcfg.seed),
+                                     state.step)
         if self.sharded:
             plead = self._sharded_update(state.plead, G, key)
         else:
-            plead = self.alg.update(state.plead, G, key)
-        Xm = plead.X
-        consensus = sum(
-            jnp.sum((l - l.mean(0, keepdims=True)) ** 2)
-            for l in jax.tree_util.tree_leaves(Xm))
+            with jax.named_scope(obs.UPDATE):
+                plead = self.alg.update(state.plead, G, key)
+        with jax.named_scope(obs.CONSENSUS):
+            consensus = sum(
+                jnp.sum((l - l.mean(0, keepdims=True)) ** 2)
+                for l in jax.tree_util.tree_leaves(plead.X))
+        with jax.named_scope(obs.UPDATE):
+            step = state.step + 1
         metrics = {"loss": ce, "consensus": consensus,
                    "step": state.step}
-        return TrainState(plead, state.step + 1, precond), metrics
+        return TrainState(plead, step, precond), metrics
 
     def _adam_precondition(self, G, precond, step):
         """Beyond-paper: per-node Adam normalization of the gradient before
@@ -427,9 +434,10 @@ class DecentralizedTrainer:
             # extra T dim ((1, T, ...)) when the plan is time-varying.
             # node_id is a P(naxes)-sharded iota: its local shard holds this
             # node's index.
-            idx = node_id[0]
-            t = jnp.asarray(step_k, jnp.int32) % T
-            wmat = jnp.asarray(wmat_np)[:, :, idx]       # (1 + hops, T)
+            with jax.named_scope(obs.EXCHANGE):
+                idx = node_id[0]
+                t = jnp.asarray(step_k, jnp.int32) % T
+                wmat = jnp.asarray(wmat_np)[:, :, idx]   # (1 + hops, T)
             leaves_X, treedef = jax.tree_util.tree_flatten(X)
             leaves = {
                 "X": leaves_X,
@@ -438,22 +446,28 @@ class DecentralizedTrainer:
                 "Hw": treedef.flatten_up_to(Hw),
                 "G": treedef.flatten_up_to(Gl),
             }
-            key_local = jax.random.fold_in(jax.random.wrap_key_data(k_arr), idx)
+            with jax.named_scope(obs.NOISE):
+                key_local = jax.random.fold_in(
+                    jax.random.wrap_key_data(k_arr), idx)
             diffs, zs, keys = [], [], []
             for j, (x, d, h, g) in enumerate(zip(
                     leaves["X"], leaves["D"], leaves["H"], leaves["G"])):
-                kj = jax.random.fold_in(key_local, j)
-                if model_id is not None and model_sharded_leaf[j]:
-                    # full-manual mode: decorrelate the stochastic-rounding
-                    # draws of the model shards — ONLY for leaves the model
-                    # axis actually shards.  Model-replicated leaves (norms,
-                    # biases) must draw identically on every shard or their
-                    # "replicated" outputs silently diverge per device
-                    # (check_rep is off).
-                    kj = jax.random.fold_in(kj, model_id[0])
-                z = x - eta * g - eta * d
+                with jax.named_scope(obs.NOISE):
+                    kj = jax.random.fold_in(key_local, j)
+                    if model_id is not None and model_sharded_leaf[j]:
+                        # full-manual mode: decorrelate the stochastic-
+                        # rounding draws of the model shards — ONLY for
+                        # leaves the model axis actually shards.  Model-
+                        # replicated leaves (norms, biases) must draw
+                        # identically on every shard or their "replicated"
+                        # outputs silently diverge per device (check_rep is
+                        # off).
+                        kj = jax.random.fold_in(kj, model_id[0])
+                with jax.named_scope(obs.UPDATE):
+                    z = x - eta * g - eta * d
+                    diff = z - h
                 zs.append(z)
-                diffs.append(z - h)
+                diffs.append(diff)
                 keys.append(kj)
             # COMM: the wire exchange produces, per leaf, the dequantized
             # self payload and W_t' Q for every round t' of the cycle
@@ -476,22 +490,23 @@ class DecentralizedTrainer:
             for j, (z, d, h, hw) in enumerate(zip(
                     zs, leaves["D"], leaves["H"], leaves["Hw"])):
                 wq_all, q_self = wq_list[j], qself_list[j]
-                zhat = h + q_self
-                if T == 1:
-                    zhat_w = hw + wq_all[0]
-                    hw_new = (1 - alpha) * hw + alpha * zhat_w
-                else:
-                    hw_t = jnp.take(hw, t, axis=1)       # slot k % T
-                    zhat_w = hw_t + jnp.take(wq_all, t, axis=0)
-                    # Hw[t'] tracks W_t' H: H += alpha Q  =>  += alpha W_t' Q
-                    hw_new = hw + alpha * jnp.moveaxis(wq_all, 0, 1)
-                dnew = d + gamma / (2 * eta) * (zhat - zhat_w)
-                v = z - gamma / 2.0 * (zhat - zhat_w)
-                xnew = self.prox(v, eta)
-                nX.append(xnew)
-                nD.append(dnew)
-                nH.append((1 - alpha) * h + alpha * zhat)
-                nHw.append(hw_new)
+                with jax.named_scope(obs.UPDATE):
+                    zhat = h + q_self
+                    if T == 1:
+                        zhat_w = hw + wq_all[0]
+                        hw_new = (1 - alpha) * hw + alpha * zhat_w
+                    else:
+                        hw_t = jnp.take(hw, t, axis=1)   # slot k % T
+                        zhat_w = hw_t + jnp.take(wq_all, t, axis=0)
+                        # Hw[t'] tracks W_t' H: H += alpha Q
+                        #   =>  += alpha W_t' Q
+                        hw_new = hw + alpha * jnp.moveaxis(wq_all, 0, 1)
+                    dnew = d + gamma / (2 * eta) * (zhat - zhat_w)
+                    v = z - gamma / 2.0 * (zhat - zhat_w)
+                    nX.append(self.prox(v, eta))
+                    nD.append(dnew)
+                    nH.append((1 - alpha) * h + alpha * zhat)
+                    nHw.append(hw_new)
             unf = lambda ls: jax.tree_util.tree_unflatten(treedef, ls)
             return unf(nX), unf(nD), unf(nH), unf(nHw)
 
@@ -502,7 +517,8 @@ class DecentralizedTrainer:
         # it runs FULL-manual: every mesh axis goes manual, specs carry the
         # per-leaf model placement (param_specs), and each model shard
         # quantizes/ppermutes its local slice independently.
-        key_data = jax.random.key_data(key)
+        with jax.named_scope(obs.NOISE):
+            key_data = jax.random.key_data(key)
         node_ids = jnp.arange(tcfg.n_nodes, dtype=jnp.int32)
         if partial_manual:
             specs = tmap(lambda l: P(naxes, *((None,) * (l.ndim - 1))),
@@ -534,5 +550,6 @@ class DecentralizedTrainer:
         nX, nD, nH, nHw = shmapped(plead.X, plead.D, plead.comm.H,
                                    plead.comm.Hw, G, key_data, plead.k,
                                    node_ids, *extra_args)
-        return ProxLEADState(nX, nD, CommState(nH, nHw), plead.oracle,
-                             plead.k + 1)
+        with jax.named_scope(obs.UPDATE):
+            k = plead.k + 1
+        return ProxLEADState(nX, nD, CommState(nH, nHw), plead.oracle, k)

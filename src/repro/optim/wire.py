@@ -17,6 +17,12 @@ sender 0 = self plus one sender per hop:
              repro.optim.decentralized's module docstring for the
              model-sharded exception).
 
+Each part runs under its named scope (repro.obs): the ppermutes and the
+identity and per-leaf mixes under ``prox_lead/exchange``; the bucketed
+path's relayouts under ``prox_lead/wire_layout`` and its noise under
+``prox_lead/noise`` (repro.core.bucket); the per-leaf quantizer, which
+draws its noise inside, under ``prox_lead/wire_layout``.
+
 All functions run INSIDE shard_map: leaves carry a leading local node dim
 of 1, ``pp(x, pairs)`` is the axis-bound ppermute closure, and ``wmat`` is
 the (1 + hops, T) receiver-indexed weight table (row 0 = self weight).
@@ -41,6 +47,7 @@ from repro.core import bucket
 from repro.kernels import ops as kops
 from repro.kernels import ref as kref
 from repro.obs.meters import current_meters
+from repro.obs.trace import EXCHANGE, WIRE_LAYOUT
 
 WIRE_MODES = ("bucketed", "per_leaf")
 
@@ -89,8 +96,11 @@ class WireExchange:
         cw, sw = bucket.pack_to_wire(layout, diffs, keys,
                                      use_pallas=self.use_pallas)
         # the ONLY communication: 2 buffers x hops, leaf-count independent
-        wires = [(cw, sw)] + [(pp(cw, pr), pp(sw, pr)) for pr in hop_pairs]
-        return bucket.mix_from_wire(layout, wires, jnp.asarray(wmat).T,
+        with jax.named_scope(EXCHANGE):
+            wires = [(cw, sw)] + [(pp(cw, pr), pp(sw, pr))
+                                  for pr in hop_pairs]
+            w = jnp.asarray(wmat).T
+        return bucket.mix_from_wire(layout, wires, w,
                                     use_pallas=self.use_pallas)
 
     # ------------------------------------------------------------ per-leaf
@@ -107,37 +117,44 @@ class WireExchange:
         bits = self.bits
         for d, kj in zip(diffs, keys):
             blk = self.block_for(d.shape)
-            codes, scales = kops.qinf_quantize_lastdim(
-                d, kj, bits=bits, block=blk)
-            if self.scales_bf16:
-                scales = scales.astype(jnp.bfloat16)
-            if self.pack_mode == "lastdim":
-                packed = kops.pack_codes_lastdim(codes, bits=bits)
-                unpack = lambda pk: kops.unpack_codes_lastdim(pk, bits=bits)
-            else:  # flat: reshape across sharded dims (baseline)
-                packed = kops.pack_codes(codes, bits=bits)
-                unpack = lambda pk: kops.unpack_codes(
-                    pk, bits=bits, n=codes.size).reshape(codes.shape)
-            # byte-cast scales: EVERY wire payload is u8
-            s_wire = jax.lax.bitcast_convert_type(scales, jnp.uint8)
+            with jax.named_scope(WIRE_LAYOUT):
+                codes, scales = kops.qinf_quantize_lastdim(
+                    d, kj, bits=bits, block=blk)
+                if self.scales_bf16:
+                    scales = scales.astype(jnp.bfloat16)
+                if self.pack_mode == "lastdim":
+                    packed = kops.pack_codes_lastdim(codes, bits=bits)
+                    unpack = lambda pk: kops.unpack_codes_lastdim(
+                        pk, bits=bits)
+                else:  # flat: reshape across sharded dims (baseline)
+                    packed = kops.pack_codes(codes, bits=bits)
+                    unpack = lambda pk: kops.unpack_codes(
+                        pk, bits=bits, n=codes.size).reshape(codes.shape)
+                # byte-cast scales: EVERY wire payload is u8
+                s_wire = jax.lax.bitcast_convert_type(scales, jnp.uint8)
             dq = lambda pk, su8, b=blk: kops.qinf_dequantize_lastdim(
                 unpack(pk),
                 jax.lax.bitcast_convert_type(
                     su8, scales.dtype).astype(jnp.float32),
                 d.shape, d.dtype, block=b)
-            recvs = [dq(pp(packed, pr), pp(s_wire, pr)) for pr in hop_pairs]
-            q_self = kops.qinf_dequantize_lastdim(
-                codes, scales.astype(jnp.float32), d.shape, d.dtype,
-                block=blk)
-            # materialize the (1 + hops, ...) payloads in the leaf's dtype
-            # and shape: left free, XLA moves the quantizer's block-to-leaf
-            # relayout past the mix's multiplies and copies every partial
-            # product out in f32 (4.6 GB more per chip for four qwen3-1.7b
-            # nodes compiled for a v5e, past its memory)
-            qstack = jax.lax.optimization_barrier(
-                jnp.stack([q_self] + recvs))
-            wq.append(kref.weighted_mix_ref(
-                jnp.asarray(wmat).T, qstack, d.dtype).astype(d.dtype))
+            with jax.named_scope(EXCHANGE):
+                got = [(pp(packed, pr), pp(s_wire, pr)) for pr in hop_pairs]
+            with jax.named_scope(WIRE_LAYOUT):
+                recvs = [dq(pk, su8) for pk, su8 in got]
+                q_self = kops.qinf_dequantize_lastdim(
+                    codes, scales.astype(jnp.float32), d.shape, d.dtype,
+                    block=blk)
+                # materialize the (1 + hops, ...) payloads in the leaf's
+                # dtype and shape: left free, XLA moves the quantizer's
+                # block-to-leaf relayout past the mix's multiplies and
+                # copies every partial product out in f32 (4.6 GB more per
+                # chip for four qwen3-1.7b nodes compiled for a v5e, past
+                # its memory)
+                qstack = jax.lax.optimization_barrier(
+                    jnp.stack([q_self] + recvs))
+            with jax.named_scope(EXCHANGE):
+                wq.append(kref.weighted_mix_ref(
+                    jnp.asarray(wmat).T, qstack, d.dtype).astype(d.dtype))
             qs.append(q_self)
         return wq, qs
 
@@ -149,8 +166,10 @@ class WireExchange:
                                        for d in diffs),
                      collectives_per_hop=len(diffs))
         wq: List = []
-        for d in diffs:
-            recvs = [pp(d, pr) for pr in hop_pairs]
-            wq.append(kref.weighted_mix_ref(
-                jnp.asarray(wmat).T, [d] + recvs, d.dtype).astype(d.dtype))
+        with jax.named_scope(EXCHANGE):
+            for d in diffs:
+                recvs = [pp(d, pr) for pr in hop_pairs]
+                wq.append(kref.weighted_mix_ref(
+                    jnp.asarray(wmat).T, [d] + recvs,
+                    d.dtype).astype(d.dtype))
         return wq, list(diffs)

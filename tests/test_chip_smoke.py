@@ -89,6 +89,7 @@ def test_compile_cache_location(tmp_path, env_dir):
     from repro.launch.cache import CACHE_DIR, use_compile_cache
     where = use_compile_cache()
     assert jax.config.jax_compilation_cache_dir == where, where
+    assert jax.config.jax_compilation_cache_include_metadata_in_key
     print("CACHE_AT", where)
     if pathlib.Path(where) != CACHE_DIR:
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
@@ -108,3 +109,31 @@ def test_compile_cache_location(tmp_path, env_dir):
         assert int(r.stdout.split("ENTRIES ")[1].split()[0]) > 0
     else:
         assert where == str(REPO / ".jax_cache")
+
+
+def test_compile_cache_hit_keeps_the_programs_op_names(tmp_path):
+    """Two builds that differ only in a named scope get their own cache
+    entries: a profile reads device time by op name, and a hit on the
+    other build would bring its names."""
+    code = """
+    import jax, jax.numpy as jnp
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+
+    def step(scope):
+        def f(x):
+            with jax.named_scope(scope):
+                return jnp.sin(x) * 3
+        return f
+
+    for scope in ("first_scope", "second_scope"):
+        text = jax.jit(step(scope)).lower(jnp.ones(8)).compile().as_text()
+        print(scope, scope in text)
+    """
+    env = dict(_env(), PYTHONPATH=str(REPO / "src"),
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jaxcache"))
+    r = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
+                       capture_output=True, text=True, env=env, timeout=300)
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert "first_scope True" in r.stdout and "second_scope True" in r.stdout

@@ -506,20 +506,21 @@ class TestNeighborBackend:
 
 @pytest.mark.slow
 class TestKernelRooflineGate:
-    """repro.obs.roofline_gate vs the exact accounting, on real meshes."""
+    """The wire layout vs the exact accounting, on real meshes."""
 
     def test_wire_roofline_matches_exact_accounting_both_meshes(self):
-        """The kernel roofline's wire bytes must equal (a) the static
-        BucketLayout, (b) netsim.metrics' bucketed/sharded payload
+        """The trainer's static BucketLayout (over its model-local leaf
+        shapes) must equal (b) netsim.metrics' bucketed/sharded payload
         accounting, (c) TrainerRunner.bits_per_step, and (d) the bytes the
         compiled HLO physically moves — on both (8,1) and (4,2) meshes.
-        If any of these ever drifts, the RunReport/roofline numbers stop
-        being trustworthy."""
+        If any of these ever drifts, the RunReport's bits stop being
+        trustworthy."""
         code = """
         import jax, jax.numpy as jnp, dataclasses, re
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from repro import api, compat, configs, obs
+        from repro import api, compat, configs
         from repro.configs import shapes as shp
+        from repro.core import bucket
         from repro.optim import DecentralizedTrainer, TrainerConfig
         from repro.obs import roofline
         from repro.netsim import metrics as nmetrics
@@ -540,17 +541,12 @@ class TestKernelRooflineGate:
             hops = len(tr.plan.hops)
             per_edge = nmetrics.bucketed_payload_bits(tr, leaves)
 
-            # (a)+(b) roofline layout == exact payload accounting
-            layout, model = obs.trainer_wire_layout(tr, leaves)
+            # (a)+(b) the static layout == exact payload accounting
+            model, locals_ = nmetrics._model_local_shapes(tr, leaves)
+            layout = bucket.compute_layout(
+                [(1,) + tuple(s) for s in locals_], [l.dtype for l in leaves],
+                bits=2, block_for=tr._quant_block)
             assert model * layout.wire_bits == per_edge, meshshape
-            v5e = roofline.PEAKS["TPU v5 lite"]
-            k = obs.kernel_roofline(layout, peaks=v5e, hops=hops)
-            assert k["wire"]["bytes_per_hop"] * 8 * model == per_edge
-            sr = obs.step_roofline(layout, peaks=v5e, hops=hops,
-                                   measured_step_s=1.0)
-            assert sr["wire_bytes_per_hop"] * 8 == layout.wire_bits
-            assert sr["predicted_step_s"] == (
-                sr["predicted_kernel_s"] + sr["predicted_wire_s"])
 
             # (c) the RunReport's bits accounting
             runner = api.TrainerRunner(tr)

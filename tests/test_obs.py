@@ -1,5 +1,6 @@
 """repro.obs telemetry: meters/spans, RunReport emission per engine,
-kernel rooflines vs exact byte accounting, and the perf gate.
+compile counters, the train step's names in a profile, and the perf
+gate.
 
 Device-light by design: everything here runs on the 1-device test process
 (the conftest pins device count); the sharded-trainer byte-equalities on
@@ -17,7 +18,6 @@ import numpy as np
 import pytest
 
 from repro import api, obs
-from repro.core import bucket
 from repro.obs.roofline import PEAKS, peaks_for
 from repro.netsim import metrics as nmetrics
 
@@ -244,57 +244,110 @@ class TestWireExchangeMeters:
 
 
 # ===========================================================================
-# Kernel roofline vs exact accounting
+# Compile counters and the train step's names in a profile
 # ===========================================================================
 
-class TestKernelRoofline:
-    SHAPES = [(4, 100), (3, 7), (64,), (2, 5, 30)]
+def _tiny_trainer_runner():
+    """A one-node trainer runner off a mesh, its state and one batch."""
+    from repro import configs
+    from repro.optim import DecentralizedTrainer, TrainerConfig
+    cfg = configs.get("qwen3-1.7b").reduced(n_layers=1, d_model=64)
+    tr = DecentralizedTrainer(cfg, TrainerConfig(n_nodes=1))
+    runner = api.TrainerRunner(tr)
+    tokens = jnp.zeros((1, 2, 8), jnp.int32)
+    return runner, runner.init_state(jax.random.key(0)), \
+        {"tokens": tokens, "labels": tokens}
 
-    def _layout(self):
-        return bucket.compute_layout(
-            self.SHAPES, [jnp.float32] * len(self.SHAPES), bits=2)
 
-    def test_wire_bytes_equal_bucket_layout(self):
-        layout = self._layout()
-        k = obs.kernel_roofline(layout, peaks=V5E, hops=3)
-        assert k["wire"]["bytes_per_hop"] * 8 == layout.wire_bits
+class TestCompileCounters:
+    def test_first_step_compiles_once_and_second_adds_none(self):
+        runner, state, batch = _tiny_trainer_runner()
+        m = obs.Meters()
+        with obs.using_meters(m):
+            state, _ = runner.step(state, batch)
+            jax.block_until_ready(state)
+        first = m.as_dict()
+        # the step's own trace, not those of the jits nested in it
+        assert first["compile/step_n"] == 1
+        assert first["compile/step_lower_s"] > 0
+        assert first["compile/backend_s"] > 0 and first["compile/n"] >= 1
+        with obs.using_meters(m):
+            state, _ = runner.step(state, batch)
+            jax.block_until_ready(state)
+        assert m.as_dict() == first
 
-    def test_wire_bytes_equal_per_leaf_qinf_accounting(self):
-        # the bucket is a concatenation of exactly the per-leaf payloads
-        layout = self._layout()
-        per_leaf = sum(
-            nmetrics.qinf_wire_bits(s, 2, bucket.default_quant_block(s))
-            for s in self.SHAPES)
-        assert layout.wire_bits == per_leaf
-        k = obs.kernel_roofline(layout, peaks=V5E)
-        assert k["wire"]["bytes_per_hop"] * 8 == per_leaf
+    def test_nothing_recorded_without_meters(self):
+        m = obs.Meters()
+        with obs.using_meters(m):        # the listeners are registered
+            pass
+        assert obs.current_meters() is None
+        jax.jit(lambda x: x * 3 + 1)(jnp.arange(5.0)).block_until_ready()
+        jax.monitoring.record_event("/jax/compilation_cache/cache_hits")
+        assert len(m) == 0
 
-    def test_hbm_model_structure(self):
-        layout = self._layout()
-        elems = sum(g.rows * g.block for g in layout.groups)
-        wire_bytes = layout.codes_bytes + layout.scales_bytes
-        k = obs.kernel_roofline(layout, peaks=V5E, hops=2, receivers=1)
-        assert k["quantize_pack"]["hbm_bytes"] == 8 * elems + wire_bytes
-        assert k["unpack_dequant_mix"]["hbm_bytes"] == \
-            3 * wire_bytes + 8 * elems
-        assert k["quantize_pack"]["t_s"] == pytest.approx(
-            k["quantize_pack"]["hbm_bytes"] / V5E.hbm_bw)
+    def test_cache_events_and_backend_seconds_forwarded(self):
+        m = obs.Meters()
+        with obs.using_meters(m):
+            jax.monitoring.record_event("/jax/compilation_cache/cache_hits")
+            jax.monitoring.record_event("/jax/compilation_cache/cache_misses")
+            jax.monitoring.record_event("/jax/compilation_cache/cache_misses")
+            jax.monitoring.record_event_duration_secs(
+                "/jax/core/compile/backend_compile_duration", 0.25,
+                fun_name="jit(f)")
+            # a persistent-cache hit's read lies inside the compile event
+            jax.monitoring.record_event_duration_secs(
+                "/jax/compilation_cache/cache_retrieval_time_sec", 0.1)
+        assert m.as_dict() == {"compile/backend_s": 0.25, "compile/n": 1,
+                               "compile/cache_hits": 1,
+                               "compile/cache_misses": 2}
 
-    def test_step_roofline_utilization(self):
-        layout = self._layout()
-        sr = obs.step_roofline(layout, peaks=V5E, hops=2,
-                               measured_step_s=1.0)
-        assert sr["predicted_step_s"] == pytest.approx(
-            sr["predicted_kernel_s"] + sr["predicted_wire_s"])
-        assert sr["utilization"] == pytest.approx(sr["predicted_step_s"])
-        assert "measured_step_s" not in obs.step_roofline(
-            layout, peaks=V5E, hops=2)
+    def test_run_report_carries_the_counters(self):
+        runner, state, batch = _tiny_trainer_runner()
 
-    def test_more_hops_more_wire_time(self):
-        layout = self._layout()
-        t1 = obs.step_roofline(layout, peaks=V5E, hops=1)["predicted_wire_s"]
-        t4 = obs.step_roofline(layout, peaks=V5E, hops=4)["predicted_wire_s"]
-        assert t4 == pytest.approx(4 * t1)
+        class Data:
+            def batch_at(self, t):
+                return batch
+
+        runner.run(num_steps=2, data=Data(), state=state)
+        rep = runner.last_report
+        assert rep.meters["compile/step_n"] == 1
+        assert rep.meters["compile/backend_s"] > 0
+        assert "roofline" not in rep.to_dict()
+        # a report written before the field went still loads
+        old = dict(rep.to_dict(), roofline={"utilization": 0.5})
+        assert obs.RunReport.from_dict(old).to_dict() == rep.to_dict()
+
+
+class TestStepNames:
+    def test_annotate_marks_steps(self):
+        assert isinstance(obs.annotate(obs.STEP, step_num=3),
+                          jax.profiler.StepTraceAnnotation)
+        assert isinstance(obs.annotate(obs.STEP_CALL),
+                          jax.profiler.TraceAnnotation)
+
+    def test_scopes_are_distinct_and_never_nest(self):
+        assert len(set(obs.SCOPES)) == len(obs.SCOPES) == 6
+        for a in obs.SCOPES:
+            for b in obs.SCOPES:
+                assert a == b or not ("/" + b + "/").startswith(
+                    "/" + a + "/"), (a, b)
+
+    def test_runner_counts_dispatched_steps(self, tmp_path):
+        from jax.profiler import ProfileData
+        runner, state, batch = _tiny_trainer_runner()
+        state, _ = runner.step(state, batch)       # compiled outside
+        jax.profiler.start_trace(str(tmp_path))
+        for _ in range(2):
+            state, _ = runner.step(state, batch)
+        jax.block_until_ready(state)
+        jax.profiler.stop_trace()
+        path = next(tmp_path.rglob("*.xplane.pb"))
+        names = [ev.name for plane in ProfileData.from_file(str(path)).planes
+                 for line in plane.lines for ev in line.events
+                 if ev.name.startswith("repro.")]
+        assert names.count(obs.STEP) == 2
+        assert names.count(obs.STEP_CALL) == 2
+        assert runner._dispatched == 3
 
 
 # ===========================================================================
