@@ -198,19 +198,15 @@ def qinf_quantize_pack(xrows: jax.Array, urows: jax.Array, *, bits: int,
     """Fused quantize + wire-pack of (R, block) rows for any R.
 
     Returns (packed u8 (R, W), scales f32 (R, 1)) with
-    W = packed_width(block, bits).  The Pallas path pads R up to ROWS_TILE
-    and slices back — padded rows exist only inside the kernel launch,
-    never on the wire."""
+    W = packed_width(block, bits).  The Pallas kernel takes any R as it
+    is (its row tile, ``quantize.pack_rows_tile``, follows R, ``block``
+    and the dtype), so nothing is padded or sliced around it."""
     if use_pallas is None:
         use_pallas = _use_pallas_default()
     if not use_pallas:
         return kref.qinf_quantize_pack_blocks_ref(xrows, urows, bits)
-    R = xrows.shape[0]
-    Rp = -(-R // qk.ROWS_TILE) * qk.ROWS_TILE
-    packed, scales = qk.qinf_quantize_pack_blocks(
-        _pad_rows(xrows, Rp), _pad_rows(urows, Rp),
-        bits=bits, block=block, interpret=_interpret_default())
-    return packed[:R], scales[:R]
+    return qk.qinf_quantize_pack_blocks(
+        xrows, urows, bits=bits, block=block, interpret=_interpret_default())
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "block", "out_dtype",

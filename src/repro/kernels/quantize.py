@@ -2,9 +2,11 @@
 
 TPU adaptation (vs. the GPU warp-shuffle reduction the paper's codebase uses):
 the quantization block size (256) is laid out along the *lane* dimension so a
-row-max is a single VPU cross-lane reduction; rows of blocks are tiled 8-at-a
--time along the sublane dimension, and each grid step streams one
-(ROWS_TILE, BLOCK) tile HBM->VMEM via BlockSpec.  Stochastic-rounding noise is
+row-max is a single VPU cross-lane reduction, and each grid step streams a
+tile of whole rows HBM->VMEM via BlockSpec.  The quantize-only and mix
+kernels tile rows 8 at a time (``ROWS_TILE``); the fused quantize+pack
+kernel of the wire path takes a tile sized to its operands
+(:func:`pack_rows_tile`).  Stochastic-rounding noise is
 a second streamed operand (precomputed with jax.random outside) so the kernel
 stays a pure function of its inputs — bit-for-bit testable against
 ``repro.kernels.ref``.
@@ -41,6 +43,34 @@ def _sender_row_tile(i):
 
 def _whole(i):
     return jnp.int32(0), jnp.int32(0)
+
+
+def _lane_tile(i):
+    return jnp.int32(0), i
+
+
+# x and noise bytes one grid step of the quantize+pack kernel streams in.
+# A grid step has a fixed cost of a fraction of a microsecond, which a tile
+# of a few rows does not cover; on a v5e the kernel's time is flat from 256
+# rows of 256 up.  This budget gives 1024 bf16 rows, whose double-buffered
+# operands and f32 temporaries take 4.7 MiB of the 16 MiB default scoped
+# VMEM.
+PACK_TILE_BYTES = 3 * 2 ** 19
+
+
+def pack_rows_tile(rows: int, block: int, dtype) -> int:
+    """Rows of one quantize+pack grid step, from the operands' shape.
+
+    Every row is its own quantization block, so the tile sets only how
+    much a grid step moves.  Up to the cap the tile is ``rows`` itself: a
+    block equal to the whole dimension is always legal, so short leaves
+    need no padding.  Above it the tile is the cap, a multiple of 128 (the
+    lanes of the scales row, and a multiple of the u8 sublane tile), and
+    the last block may be ragged: its rows past ``rows`` reach no stored
+    output.  A row narrower than 128 lanes still fills 128 in VMEM."""
+    per_row = max(block, 128) * (jnp.dtype(dtype).itemsize + 4)
+    cap = max(128, PACK_TILE_BYTES // per_row // 128 * 128)
+    return rows if rows <= cap else cap
 
 
 def packed_width(block: int, bits: int) -> int:
@@ -124,7 +154,8 @@ def _quantize_pack_kernel(x_ref, u_ref, packed_ref, scales_ref, *, bits: int):
         half = enc.shape[-1] // 2
         enc = enc[:, :half] | (enc[:, half:] << 4)
     packed_ref[...] = enc.astype(jnp.uint8)
-    scales_ref[...] = (maxabs / levels).astype(jnp.float32)
+    # lane-dense: the tile's (rows, 1) scales column as a (1, rows) row
+    scales_ref[...] = (maxabs / levels).astype(jnp.float32).T
 
 
 def _unpack_dequant_mix_kernel(p_ref, s_ref, w_ref, mix_ref, qself_ref, *,
@@ -160,31 +191,32 @@ def qinf_quantize_pack_blocks(xb: jax.Array, ub: jax.Array, *, bits: int,
                               block: int = 256, interpret: bool = True):
     """Fused quantize+pack: (R, block) float rows (upcast to f32 in VMEM)
     -> (packed u8 (R, W), scales f32 (R, 1)), W = packed_width(block,
-    bits).  R % ROWS_TILE == 0 (callers pad for the kernel and slice the
-    output; padded rows never reach the wire).
+    bits), for any R.  The grid steps over :func:`pack_rows_tile` rows;
+    the kernel writes the scales as one lane-dense (1, R) row, which a
+    (R, 1) column would pad to 128 lanes, and the reshape back is free.
     """
     R, B = xb.shape
     assert B == block, (xb.shape, block)
-    assert R % ROWS_TILE == 0, f"R={R} must be a multiple of {ROWS_TILE}"
     W = packed_width(block, bits)
-    grid = (R // ROWS_TILE,)
-    return pl.pallas_call(
+    tile = pack_rows_tile(R, block, xb.dtype)
+    packed, scales = pl.pallas_call(
         functools.partial(_quantize_pack_kernel, bits=bits),
-        grid=grid,
+        grid=(pl.cdiv(R, tile),),
         in_specs=[
-            pl.BlockSpec((ROWS_TILE, block), _row_tile),
-            pl.BlockSpec((ROWS_TILE, block), _row_tile),
+            pl.BlockSpec((tile, block), _row_tile),
+            pl.BlockSpec((tile, block), _row_tile),
         ],
         out_specs=[
-            pl.BlockSpec((ROWS_TILE, W), _row_tile),
-            pl.BlockSpec((ROWS_TILE, 1), _row_tile),
+            pl.BlockSpec((tile, W), _row_tile),
+            pl.BlockSpec((1, tile), _lane_tile),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((R, W), jnp.uint8),
-            jax.ShapeDtypeStruct((R, 1), jnp.float32),
+            jax.ShapeDtypeStruct((1, R), jnp.float32),
         ],
         interpret=interpret,
     )(xb, ub)
+    return packed, scales.reshape(R, 1)
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "block", "out_dtype",

@@ -158,8 +158,8 @@ class TestFusedKernels:
 
     @pytest.mark.parametrize("rows", [1, 7, 13])
     def test_ops_wrapper_pads_and_slices(self, rows):
-        """The ops dispatch pads ragged row counts for the kernel and
-        slices back — pallas and ref agree for any R."""
+        """The ops dispatch hands the kernel any row count as it is —
+        pallas and ref agree for any R."""
         x = jax.random.normal(jax.random.key(0), (rows, 128))
         u = jax.random.uniform(jax.random.key(1), (rows, 128))
         for use_pallas in (False, True):

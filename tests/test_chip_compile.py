@@ -47,9 +47,13 @@ def one_chip():
     jax.config.update("jax_enable_compilation_cache", was)
 
 
-def _compile(fn, *shapes, sharding):
+def _compiled(fn, *shapes, sharding):
     args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
-    return jax.jit(fn).lower(*args).compile().as_text()
+    return jax.jit(fn).lower(*args).compile()
+
+
+def _compile(fn, *shapes, sharding):
+    return _compiled(fn, *shapes, sharding=sharding).as_text()
 
 
 @pytest.mark.parametrize("bits", [2, 4])
@@ -60,6 +64,20 @@ def test_quantize_pack_compiles(one_chip, block, bits):
     hlo = _compile(fn, ((ROWS, block), jnp.float32),
                    ((ROWS, block), jnp.float32), sharding=one_chip)
     assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("rows", [1_024_000, 352_256, 16])
+def test_quantize_pack_compiles_at_published_rows(one_chip, rows):
+    """yi-9b's embed (and lm_head), stacked MLP and norm leaves as the
+    bucketed wire quantizes them: bf16 rows of 256.  The scales leave the
+    kernel as a lane-dense row; a (R, 1) f32 column would be laid out at
+    128 lanes, 512 B a row of temp, and copied again after the kernel."""
+    fn = functools.partial(qk.qinf_quantize_pack_blocks, bits=2, block=256,
+                           interpret=False)
+    compiled = _compiled(fn, ((rows, 256), jnp.bfloat16),
+                         ((rows, 256), jnp.float32), sharding=one_chip)
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < rows * 4 * 2
 
 
 @pytest.mark.parametrize("T", [1, 4])

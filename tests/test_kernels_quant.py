@@ -150,3 +150,32 @@ def test_weighted_mix_same_bits_in_any_view(dtype):
     want = np.tensordot(np.asarray(w, np.float64), np.asarray(q, np.float64),
                         axes=(1, 0))
     np.testing.assert_allclose(np.asarray(a), want, rtol=1e-6, atol=1e-6)
+
+
+# row counts of the quantize+pack kernel, as functions of its row tile's cap:
+# under the cap (a single block the size of R), at it, and past it with a
+# ragged last block
+PACK_ROWS = {"8": lambda cap: 8, "16": lambda cap: 16, "40": lambda cap: 40,
+             "cap": lambda cap: cap, "cap+8": lambda cap: cap + 8,
+             "2cap+24": lambda cap: 2 * cap + 24}
+
+
+@pytest.mark.parametrize("rows", list(PACK_ROWS))
+@pytest.mark.parametrize("block", [32, 64, 128, 256])
+@pytest.mark.parametrize("bits", [2, 4])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_quantize_pack_tiles_match_ref(rows, block, bits, dtype):
+    """The quantize+pack kernel at its row tiles gives the oracle's packed
+    codes and scales bit for bit, for R below, at and past the cap."""
+    cap = qk.pack_rows_tile(2 ** 30, block, dtype)
+    assert cap % 128 == 0
+    R = PACK_ROWS[rows](cap)
+    assert qk.pack_rows_tile(R, block, dtype) == min(R, cap)
+    x = (jax.random.normal(jax.random.key(R), (R, block)) * 3).astype(dtype)
+    u = jax.random.uniform(jax.random.key(1), (R, block), jnp.float32)
+    pk, sk = qk.qinf_quantize_pack_blocks(x, u, bits=bits, block=block,
+                                          interpret=True)
+    pr, sr = kref.qinf_quantize_pack_blocks_ref(x, u, bits)
+    assert pk.shape == (R, qk.packed_width(block, bits)) and sk.shape == (R, 1)
+    np.testing.assert_array_equal(np.asarray(pk), np.asarray(pr))
+    np.testing.assert_array_equal(np.asarray(sk), np.asarray(sr))
