@@ -9,5 +9,8 @@ metric lives in a file of its own, found by name:
   traffic/<traffic>.json    nodes, mesh, gossip, compressor, batch, tokens
   limits/<cell>.json        the limit of each number ``correct`` compares
   metrics/<metric>.py       one reader per per-layer metric
-  reference/<name>.py       plain references the comparison runs
+  reference/<name>.py       a model kind, named by a configuration's
+                            ``reference`` key: shapes, counts, program
+                            mapping, reference (bench/kinds.py)
+  reference/prox_lead.py    the plain Prox-LEAD reference over any kind
 """

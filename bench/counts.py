@@ -1,9 +1,8 @@
 """Operations and bytes the work needs, computed from shapes.
 
-Model FLOPs follow the PaLM paper's appendix B: 6 x the matmul weights per
-token, plus 12 x layers x seq x heads x head_dim for attention.  The matmul
-weights are every weight matrix but the embedding table (a gather does no
-matmul); ``lm_head`` counts, over the published vocabulary.
+Model FLOPs are the configuration's model kind's (bench/kinds.py): for the
+dense decoder, the PaLM paper's appendix B, 6 x the matmul weights per
+token plus the attention term.
 
 Kernel bytes are what the algorithm needs, whatever implements it:
 quantize+pack reads each leaf once in its dtype and writes ``bits`` bits
@@ -19,23 +18,20 @@ from typing import Iterable, Tuple
 
 import numpy as np
 
-from bench import weights
+from bench import kinds, weights
 
 SCALE_BYTES = 4             # one f32 scale per quantization block
 
 
 def matmul_weights(cfg: dict) -> int:
-    D, F = cfg["hidden_size"], cfg["intermediate_size"]
-    H, KV, hd = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
-                 cfg["head_dim"])
-    per_layer = D * H * hd + 2 * D * KV * hd + H * hd * D + 3 * D * F
-    return cfg["num_hidden_layers"] * per_layer + D * cfg["vocab_size"]
+    """Weights one token multiplies, by the configuration's model kind."""
+    return kinds.of(cfg).matmul_weights(cfg)
 
 
 def flops_per_token(cfg: dict, seq_len: int) -> float:
-    attn = (12 * cfg["num_hidden_layers"] * seq_len
-            * cfg["num_attention_heads"] * cfg["head_dim"])
-    return 6.0 * matmul_weights(cfg) + attn
+    """Model FLOPs of one token, forward and backward, by the
+    configuration's model kind."""
+    return kinds.of(cfg).flops_per_token(cfg, seq_len)
 
 
 def quant_block(shape: Tuple[int, ...], block: int) -> int:

@@ -5,4 +5,4 @@ from bench import scopes
 
 
 def read(ctx):
-    return scopes.update_part_ms(ctx, scopes.NOISE)
+    return scopes.part_ms(ctx, scopes.NOISE, "other")

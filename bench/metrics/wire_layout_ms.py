@@ -6,4 +6,4 @@ from bench import scopes
 
 
 def read(ctx):
-    return scopes.update_part_ms(ctx, scopes.WIRE_LAYOUT)
+    return scopes.part_ms(ctx, scopes.WIRE_LAYOUT, "other")

@@ -1,4 +1,6 @@
-"""Plain reference of a llama-style dense decoder: loss and gradient.
+"""The dense decoder kind (bench/kinds.py): a llama-style decoder's
+parameter shapes, model FLOPs, the program's fields for it, and its plain
+reference loss and gradient.
 
 Pre-norm RMSNorm blocks; grouped-query attention with RoPE (rotate-half
 form, positions 0..S-1) and optional RMSNorm on each q and k head (Qwen3);
@@ -9,11 +11,76 @@ accumulated over the batch one row at a time and returned in the
 parameters' dtype."""
 from __future__ import annotations
 
+from typing import Dict
+
 import jax
 import jax.numpy as jnp
 
+from bench.weights import padded_vocab
+
 F32 = jnp.float32
 HI = jax.lax.Precision.HIGHEST
+
+
+def param_shapes(cfg: dict) -> Dict[str, object]:
+    """``embed`` (Vp, D), ``final_norm`` (D,), ``lm_head`` (D, Vp) and the
+    layer-stacked ``blocks``, each leaf (shape, fan-in or None)."""
+    D, F = cfg["hidden_size"], cfg["intermediate_size"]
+    H, KV, hd = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
+                 cfg["head_dim"])
+    L, Vp = cfg["num_hidden_layers"], padded_vocab(cfg)
+    blocks = {
+        "ln1": ((L, D), None),
+        "wq": ((L, D, H * hd), D),
+        "wk": ((L, D, KV * hd), D),
+        "wv": ((L, D, KV * hd), D),
+        "wo": ((L, H * hd, D), H * hd),
+        "ln2": ((L, D), None),
+        "w_gate": ((L, D, F), D),
+        "w_up": ((L, D, F), D),
+        "w_down": ((L, F, D), F),
+    }
+    if cfg.get("qk_norm"):
+        blocks["q_norm"] = ((L, hd), None)
+        blocks["k_norm"] = ((L, hd), None)
+    return {"embed": ((Vp, D), D), "final_norm": ((D,), None),
+            "lm_head": ((D, Vp), D), "blocks": blocks}
+
+
+def matmul_weights(cfg: dict) -> int:
+    """Every weight matrix but the embedding table (a gather does no
+    matmul); ``lm_head`` counts, over the published vocabulary."""
+    D, F = cfg["hidden_size"], cfg["intermediate_size"]
+    H, KV, hd = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
+                 cfg["head_dim"])
+    per_layer = D * H * hd + 2 * D * KV * hd + H * hd * D + 3 * D * F
+    return cfg["num_hidden_layers"] * per_layer + D * cfg["vocab_size"]
+
+
+def flops_per_token(cfg: dict, seq_len: int) -> float:
+    """The PaLM paper's appendix B: 6 x the matmul weights, plus 12 x
+    layers x seq x heads x head_dim for attention."""
+    attn = (12 * cfg["num_hidden_layers"] * seq_len
+            * cfg["num_attention_heads"] * cfg["head_dim"])
+    return 6.0 * matmul_weights(cfg) + attn
+
+
+def program_params(cfg: dict) -> dict:
+    """The program's ModelConfig fields for a configuration file."""
+    if cfg["hidden_act"] != "silu" or cfg["tie_word_embeddings"]:
+        raise ValueError("the program's dense decoder is SwiGLU with an "
+                         "untied lm_head")
+    if cfg["rms_norm_eps"] != 1e-6 or cfg["padded_vocab_multiple"] != 256:
+        raise ValueError("the program's RMSNorm eps is 1e-6 and it pads the "
+                         "vocabulary to a multiple of 256")
+    return {"n_layers": cfg["num_hidden_layers"],
+            "d_model": cfg["hidden_size"],
+            "n_heads": cfg["num_attention_heads"],
+            "n_kv_heads": cfg["num_key_value_heads"],
+            "head_dim": cfg["head_dim"], "d_ff": cfg["intermediate_size"],
+            "vocab": cfg["vocab_size"], "rope_theta": cfg["rope_theta"],
+            "qk_norm": bool(cfg.get("qk_norm", False)),
+            "dtype": cfg["torch_dtype"]}
 
 
 def _rms(x, scale, eps):

@@ -18,14 +18,14 @@ QInf draws its own stochastic-rounding noise.  With compressor
 from __future__ import annotations
 
 import functools
+import json
 import math
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import counts, graph, seeds
-from bench.reference import dense_decoder
+from bench import counts, graph, kinds, seeds
 
 F32 = jnp.float32
 
@@ -108,9 +108,7 @@ class Reference:
         self.H = [zeros(x) for x in x0s]
         self.Hw = [zeros(x) for x in x0s]
         self.k = 0
-        self._lg = _loss_grad_fn(tuple(sorted(
-            (k, v) for k, v in cfg.items()
-            if isinstance(v, (int, float, str, bool)))))
+        self._lg = _loss_grad_fn(kinds.frozen(cfg))
 
     def step(self, batch):
         """One step on host batch dict of (N, B, S) arrays; returns the
@@ -154,6 +152,8 @@ class Reference:
 
 
 @functools.lru_cache(maxsize=None)
-def _loss_grad_fn(cfg_t):
-    cfg = dict(cfg_t)
-    return jax.jit(lambda p, t, l: dense_decoder.loss_and_grad(cfg, p, t, l))
+def _loss_grad_fn(cfg_json: str):
+    """The jitted loss and gradient of the configuration's model kind."""
+    cfg = json.loads(cfg_json)
+    loss_and_grad = kinds.of(cfg).loss_and_grad
+    return jax.jit(lambda p, t, l: loss_and_grad(cfg, p, t, l))

@@ -40,13 +40,10 @@ if ROOT not in sys.path:
 
 import jax  # noqa: E402
 
-from bench import check, graph, traffic, weights  # noqa: E402
+from bench import check, graph, kinds, traffic, weights  # noqa: E402
 from bench import trace as traces  # noqa: E402
+from bench.kinds import BenchError  # noqa: E402
 from bench.reference import prox_lead  # noqa: E402
-
-
-class BenchError(RuntimeError):
-    """A run that cannot measure: no result is printed."""
 
 
 # --------------------------------------------------------------- manifest
@@ -64,6 +61,7 @@ def load_cell(name: str, manifest: dict) -> dict:
     cell = dict(cells[name])
     configs = {c["name"]: c for c in manifest["configs"]}
     cell["cfg"] = load_json(os.path.join(ROOT, configs[cell["config"]]["file"]))
+    kinds.of(cell["cfg"])          # an unknown model kind measures nothing
     cell["traffic_params"] = load_json(
         os.path.join(BENCH, "traffic", cell["traffic"] + ".json"))
     tr = cell["traffic_params"]
@@ -106,21 +104,12 @@ def tpu_devices(chips: int):
 
 # --------------------------------------------------------------- program
 def model_params(cfg: dict) -> dict:
-    """The program's ModelConfig fields for a configuration file."""
-    if cfg["hidden_act"] != "silu" or cfg["tie_word_embeddings"]:
-        raise BenchError("the program's dense decoder is SwiGLU with an "
-                         "untied lm_head")
-    if cfg["rms_norm_eps"] != 1e-6 or cfg["padded_vocab_multiple"] != 256:
-        raise BenchError("the program's RMSNorm eps is 1e-6 and it pads the "
-                         "vocabulary to a multiple of 256")
-    return {"n_layers": cfg["num_hidden_layers"],
-            "d_model": cfg["hidden_size"],
-            "n_heads": cfg["num_attention_heads"],
-            "n_kv_heads": cfg["num_key_value_heads"],
-            "head_dim": cfg["head_dim"], "d_ff": cfg["intermediate_size"],
-            "vocab": cfg["vocab_size"], "rope_theta": cfg["rope_theta"],
-            "qk_norm": bool(cfg.get("qk_norm", False)),
-            "dtype": cfg["torch_dtype"]}
+    """The program's ModelConfig fields for a configuration file, by its
+    model kind."""
+    try:
+        return kinds.of(cfg).program_params(cfg)
+    except ValueError as e:
+        raise BenchError(str(e)) from None
 
 
 # The program's own stochastic-rounding seed.  The trainer compiles it into
@@ -367,8 +356,8 @@ def reference_reading(cfg: dict, tr: dict, seed: int, devices, *,
     del x0
     ref = prox_lead.Reference(cfg, tr, seed, x0s, devices[:N], faults=faults)
     del x0s
-    cfg_t = tuple(sorted(_scalars(cfg).items()))
-    grad_fn, change_fn = _ref_grad_fn(cfg_t, eta), _ref_change_fn(cfg_t)
+    key = kinds.frozen(cfg)
+    grad_fn, change_fn = _ref_grad_fn(key, eta), _ref_change_fn(key)
     losses, grad = [], None
     for k in range(K):
         losses.append(ref.step(host[k]))
@@ -390,21 +379,17 @@ def _node_sum(parts):
     return total
 
 
-def _scalars(cfg: dict) -> dict:
-    return {k: v for k, v in cfg.items()
-            if isinstance(v, (int, float, str, bool))}
-
-
+# keyed on the whole configuration (kinds.frozen), nested entries too
 @functools.lru_cache(maxsize=None)
-def _ref_grad_fn(cfg_t, eta):
-    cfg = dict(cfg_t)
+def _ref_grad_fn(cfg_json: str, eta):
+    cfg = json.loads(cfg_json)
     return jax.jit(lambda k, X, D: check.grad_sq(
         weights.init_params(cfg, k), X, D, eta))
 
 
 @functools.lru_cache(maxsize=None)
-def _ref_change_fn(cfg_t):
-    cfg = dict(cfg_t)
+def _ref_change_fn(cfg_json: str):
+    cfg = json.loads(cfg_json)
     return jax.jit(lambda k, X, D, H, Hw: check.change_sq(
         weights.init_params(cfg, k), X, D, H, Hw))
 

@@ -165,9 +165,21 @@ class Context:
         """Device seconds per step (per chip) of one layer's ops; None
         where the trace holds none of them."""
         meta = self.meta()
-        picked = [o for o in self.table.ops
-                  if layers.classify(o.name, meta) == layer]
-        if not picked or not self.steps:
+        return self._s_per_step(o for o in self.table.ops
+                                if layers.classify(o.name, meta) == layer)
+
+    def named_s_per_step(self, name: str) -> Optional[float]:
+        """Device seconds per step (per chip) of the ops whose ``op_name``
+        holds ``name``, such as a kernel's ``jit(<entry>)``, in whatever
+        layer they lie; None where the trace holds none of them."""
+        meta = self.meta()
+        return self._s_per_step(
+            o for o in self.table.ops
+            if name in meta.get(o.name, ("", ""))[1]
+            and layers.classify(o.name, meta) != "container")
+
+    def _s_per_step(self, ops) -> Optional[float]:
+        durs = [o.dur_ns for o in ops]
+        if not durs or not self.steps:
             return None
-        return (sum(o.dur_ns for o in picked) / len(self.table.devices)
-                / 1e9 / self.steps)
+        return sum(durs) / len(self.table.devices) / 1e9 / self.steps

@@ -1,11 +1,12 @@
 """A node's parameters, made from the seed on the device.
 
-The layout is the state layout the trainer takes for a dense decoder:
-``embed`` (Vp, D), ``final_norm`` (D,), ``lm_head`` (D, Vp) and the
-layer-stacked ``blocks``; the vocabulary is padded to a multiple of
+The layout is the state layout the trainer takes for the configuration's
+model kind (``param_shapes`` of bench/reference/<reference>.py, found by
+bench/kinds.py); the vocabulary is padded to a multiple of
 ``padded_vocab_multiple`` rows (the padded logits take part in the softmax,
-in the program and in the reference alike).  Matrices are normal with
-standard deviation 1/sqrt(fan-in), norm scales are 1."""
+in the program and in the reference alike).  Leaf j, in the tree's
+flattening order, is normal with standard deviation 1/sqrt(fan-in) from
+the seed's key folded with j; norm scales are 1."""
 from __future__ import annotations
 
 import math
@@ -14,7 +15,7 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from bench import seeds
+from bench import kinds, seeds
 
 
 def padded_vocab(cfg: dict) -> int:
@@ -25,26 +26,7 @@ def padded_vocab(cfg: dict) -> int:
 def param_shapes(cfg: dict) -> Dict[str, object]:
     """{name: (shape, fan_in or None for a norm scale)}, nested like the
     trainer's parameter tree."""
-    D, F = cfg["hidden_size"], cfg["intermediate_size"]
-    H, KV, hd = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
-                 cfg["head_dim"])
-    L, Vp = cfg["num_hidden_layers"], padded_vocab(cfg)
-    blocks = {
-        "ln1": ((L, D), None),
-        "wq": ((L, D, H * hd), D),
-        "wk": ((L, D, KV * hd), D),
-        "wv": ((L, D, KV * hd), D),
-        "wo": ((L, H * hd, D), H * hd),
-        "ln2": ((L, D), None),
-        "w_gate": ((L, D, F), D),
-        "w_up": ((L, D, F), D),
-        "w_down": ((L, F, D), F),
-    }
-    if cfg.get("qk_norm"):
-        blocks["q_norm"] = ((L, hd), None)
-        blocks["k_norm"] = ((L, hd), None)
-    return {"embed": ((Vp, D), D), "final_norm": ((D,), None),
-            "lm_head": ((D, Vp), D), "blocks": blocks}
+    return kinds.of(cfg).param_shapes(cfg)
 
 
 def _is_entry(x) -> bool:

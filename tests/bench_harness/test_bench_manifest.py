@@ -13,6 +13,42 @@ UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 PATH = re.compile(r"^[A-Za-z0-9_.\-/]{1,200}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 
+# A cut may take depth, and a chip's share of a deployment in which
+# ``deployment_chips_per_layer`` chips share each layer: its slice of the
+# vocabulary and its routed experts, down to the guide's floors.  No width
+# is ever cut: a hidden, intermediate, latent or projection size, a head
+# count or size, a rank, an expansion factor, the experts per token.
+CHIPS_PER_LAYER = "deployment_chips_per_layer"
+EXPERT_COUNTS = ("n_routed_experts", "num_local_experts", "num_experts")
+WIDTH = re.compile(r"(_dim|_rank|_size)$|heads|per_tok|expan")
+
+
+def reduction_faults(cfg: dict) -> list:
+    """What breaks the rules of a cut in a configuration file's
+    ``reduced`` keys, against its ``published`` values."""
+    faults = []
+    pub = cfg["published"]
+    for key in cfg["reduced"]:
+        if key == "vocab_size":
+            n = cfg.get(CHIPS_PER_LAYER)
+            if not isinstance(n, int) or n < 1:
+                faults.append(f"vocab_size cut without {CHIPS_PER_LAYER}")
+            elif cfg[key] * n != pub[key]:
+                faults.append(f"vocab_size {cfg[key]} is not {pub[key]} "
+                              f"over {n} chips")
+            if cfg[key] * 8 < pub[key]:
+                faults.append(f"vocab_size {cfg[key]} is under an eighth")
+        elif key in EXPERT_COUNTS:
+            if cfg[key] < 8 or cfg[key] * 8 < pub[key]:
+                faults.append(f"{key} {cfg[key]} is under 8 or an eighth "
+                              f"of {pub[key]}")
+        elif WIDTH.search(key):
+            faults.append(f"{key} is a width")
+        if isinstance(pub[key], dict):
+            faults += [f"{key}.{k} is a width" for k, v in pub[key].items()
+                       if WIDTH.search(k) and cfg[key].get(k) != v]
+    return faults
+
 
 @pytest.fixture(scope="module")
 def manifest():
@@ -64,8 +100,7 @@ def test_configs_exist_and_match(manifest):
         for key in c["reduced"]:
             assert NAME.match(key)
             assert cfg["published"][key] != cfg[key]
-            assert not (key.endswith("_dim") or key.endswith("_size")
-                        or key.endswith("_rank") or "heads" in key), key
+        assert reduction_faults(cfg) == []
         assert c["file"] not in files
         files.add(c["file"])
         assert os.path.isfile(os.path.join(ROOT, "bench", "reference",
@@ -118,3 +153,66 @@ def test_metrics(manifest):
         assert any(cell in m.get("workloads", [cell])
                    for m in manifest["per_layer"])
     assert len(json.dumps(manifest)) <= 64 * 1024
+
+
+# DeepSeek-V2-Lite as published (its config.json), the sizes a cut reads
+DSV2_LITE = {"num_hidden_layers": 27, "hidden_size": 2048,
+             "intermediate_size": 10944, "moe_intermediate_size": 1408,
+             "num_attention_heads": 16, "kv_lora_rank": 512,
+             "qk_nope_head_dim": 128, "qk_rope_head_dim": 64,
+             "v_head_dim": 128, "n_routed_experts": 64, "n_shared_experts": 2,
+             "num_experts_per_tok": 6, "vocab_size": 102400,
+             "rope_scaling": {"type": "yarn", "factor": 40,
+                              "original_max_position_embeddings": 4096}}
+
+
+def _cut(chips=None, **changes):
+    cfg = dict(DSV2_LITE, **changes)
+    cfg["reduced"] = list(changes)
+    cfg["published"] = {k: DSV2_LITE[k] for k in changes}
+    if chips is not None:
+        cfg[CHIPS_PER_LAYER] = chips
+    return cfg
+
+
+@pytest.mark.parametrize("cfg", [
+    _cut(num_hidden_layers=5),
+    _cut(chips=8, vocab_size=12800),
+    _cut(chips=2, vocab_size=51200),
+    _cut(chips=8, n_routed_experts=8),
+    _cut(n_routed_experts=16),
+    _cut(chips=8, num_hidden_layers=5, n_routed_experts=8, vocab_size=12800),
+    _cut(rope_scaling={"type": "yarn", "factor": 40,
+                       "original_max_position_embeddings": 2048}),
+], ids=["depth", "vocab_eighth", "vocab_half", "experts_8_of_64",
+        "experts_16_of_64", "deepseek_share", "nested_group"])
+def test_cut_within_the_floors_is_taken(cfg):
+    assert reduction_faults(cfg) == []
+
+
+@pytest.mark.parametrize("cfg,fault", [
+    (_cut(vocab_size=12800), "without deployment_chips_per_layer"),
+    (_cut(chips=8, vocab_size=12000), "is not 102400 over 8 chips"),
+    (_cut(chips=16, vocab_size=6400), "under an eighth"),
+    (_cut(chips=8, n_routed_experts=4), "under 8"),
+    (dict(_cut(n_routed_experts=8), published={"n_routed_experts": 128}),
+     "under 8 or an eighth of 128"),
+    (_cut(hidden_size=1024), "hidden_size is a width"),
+    (_cut(intermediate_size=5472), "intermediate_size is a width"),
+    (_cut(moe_intermediate_size=704), "moe_intermediate_size is a width"),
+    (_cut(kv_lora_rank=256), "kv_lora_rank is a width"),
+    (_cut(qk_rope_head_dim=32), "qk_rope_head_dim is a width"),
+    (_cut(v_head_dim=64), "v_head_dim is a width"),
+    (_cut(num_attention_heads=8), "num_attention_heads is a width"),
+    (_cut(num_experts_per_tok=2), "num_experts_per_tok is a width"),
+    (dict(DSV2_LITE, text_config={"hidden_size": 1024, "layers": 5},
+          reduced=["text_config"],
+          published={"text_config": {"hidden_size": 2048, "layers": 27}}),
+     "text_config.hidden_size is a width"),
+], ids=["vocab_no_chips", "vocab_not_share", "vocab_under_eighth",
+        "experts_under_8", "experts_under_eighth", "hidden", "intermediate",
+        "expert_width", "latent_rank", "rope_head_dim", "v_head_dim",
+        "heads", "experts_per_token", "nested_width"])
+def test_cut_past_the_floors_or_of_a_width_is_refused(cfg, fault):
+    assert any(fault in f for f in reduction_faults(cfg)), \
+        reduction_faults(cfg)

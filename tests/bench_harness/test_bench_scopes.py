@@ -260,3 +260,36 @@ def test_live_trace_of_runner_steps_reads_host_step_ms(programs, tmp_path,
     table = trace.load(str(trace_dir), jax.devices()[:1])
     ms = _read("host_step_ms", _ctx(table, "", steps=len(steps), cell=cell))
     assert ms == pytest.approx(spans.step_ms(got)) and ms > 0
+
+
+# ---- a scope a reader asks for by name, nested in the program's own
+NESTED_HLO = """
+ENTRY %main {
+  %fusion.1 = bf16[8]{0} fusion(%p), kind=kLoop, calls=%f1, metadata={op_name="jit(train_step)/fwd_bwd/jvp(vmap())/moe/dispatch/dot_general"}
+  %fusion.2 = bf16[8]{0} fusion(%p), kind=kLoop, calls=%f2, metadata={op_name="jit(train_step)/fwd_bwd/transpose(jvp(vmap()))/while/body/moe/dispatch/dot_general"}
+  %fusion.3 = bf16[8]{0} fusion(%p), kind=kLoop, calls=%f3, metadata={op_name="jit(train_step)/fwd_bwd/jvp(vmap())/attention/dot_general"}
+  %fusion.4 = bf16[8]{0} fusion(%p), kind=kLoop, calls=%f4, metadata={op_name="jit(train_step)/fwd_bwd/jvp(vmap())/moe/dispatched/add"}
+  %fusion.5 = bf16[8]{0} fusion(%p), kind=kLoop, calls=%f5, metadata={op_name="jit(train_step)/prox_lead/update/moe/dispatch/mul"}
+  %fusion.6 = bf16[8]{0} fusion(%p), kind=kLoop, calls=%f6, metadata={op_name="jit(train_step)/consensus/mul;jit(train_step)/fwd_bwd/jvp(vmap())/moe/dispatch/mul"}
+  %fusion.7 = bf16[8]{0} fusion(%p), kind=kLoop, calls=%f7, metadata={op_name="jit(train_step)/fwd_bwd/jvp(vmap())/mul"}
+}
+"""
+
+
+@pytest.mark.parametrize("scope,layer,ms", [
+    ("moe/dispatch", "fwd_bwd", 1 + 2),     # under fwd_bwd, loop body too
+    ("moe", "fwd_bwd", 1 + 2 + 4),          # the whole segment 'moe' only
+    ("attention", "fwd_bwd", 3),
+    ("moe/dispatch", "other", 5),           # innermost of update's
+    (scopes.UPDATE, "other", 5),            # update's own reader keeps it
+    (scopes.CONSENSUS, "fwd_bwd", 6),       # the first merged name decides
+    (scopes.FWD_BWD, "fwd_bwd", 1 + 2 + 3 + 4 + 7),  # nested ones too
+    ("moe/dispatch", "mix", None),
+    ("router", "fwd_bwd", None),
+])
+def test_part_ms_finds_a_scope_by_name(scope, layer, ms):
+    ops = [_op(0, f"fusion.{i}", 10 * i * 1_000_000, i * 1_000_000)
+           for i in range(1, 8)]
+    ctx = _ctx(trace.table_from(ops, [], (0,)), NESTED_HLO)
+    got = scopes.part_ms(ctx, scope, layer)
+    assert got == (None if ms is None else pytest.approx(ms))

@@ -68,6 +68,36 @@ def test_kernel_time_per_step():
     assert ctx.layer_s_per_step("permute") is None
 
 
+NAMED_HLO = HLO.replace("}\n", "", 1).rstrip().rstrip("}") + """
+  %custom-call.7 = bf16[8]{0} custom-call(%p), custom_call_target="tpu_custom_call", metadata={op_name="jit(train_step)/fwd_bwd/jvp(vmap())/jit(moe_gmm)/pallas_call"}
+  %custom-call.8 = bf16[8]{0} custom-call(%p), custom_call_target="tpu_custom_call", metadata={op_name="jit(train_step)/fwd_bwd/transpose(jvp(vmap()))/jit(moe_gmm)/pallas_call"}
+  %while.9 = bf16[8]{0} while(%p), condition=%c, body=%b, metadata={op_name="jit(train_step)/jit(moe_gmm)/while"}
+}
+"""
+
+
+@pytest.mark.parametrize("name,want", [
+    ("jit(moe_gmm)", 2e-3 + 3e-3),           # forward and backward, no loop
+    ("jit(qinf_quantize_pack)", 4e-3),
+    ("jit(qinf_unpack_dequant_mix)", 5e-3),
+    ("jit(no_such_kernel)", None),
+])
+def test_named_time_per_step_sums_a_kernels_ops(name, want):
+    durs = {"custom-call.7": 2, "custom-call.8": 3, "while.9": 100,
+            "custom-call.3": 4, "custom-call.4": 5, "fusion.1": 6}
+    ops = [_op(0, n, 200 * i * 1_000_000, d * 1_000_000)
+           for i, (n, d) in enumerate(durs.items())]
+    ops += [_op(0, n, o.start_ns + 100_000_000_000, o.dur_ns)
+            for o in ops for n in [o.name]]        # a second step
+    ctx = trace.Context(cell={}, table=trace.table_from(ops, [], (0,)),
+                        steps=2, tokens=0, window_s=0.0, chips=1,
+                        device_kind="TPU v5 lite",
+                        hlo_text=lambda: NAMED_HLO)
+    got = ctx.named_s_per_step(name)
+    assert got == (None if want is None else pytest.approx(want))
+    assert ctx.layer_s_per_step("fwd_bwd") == pytest.approx(6e-3 + 5e-3)
+
+
 # ---- a trace recorded on a TPU v5e: two steps of a tiny qwen3-layout node
 # (d_model 128, 2 layers, 2 x 64 tokens, QInf 2-bit bucketed), with the
 # compiled step's HLO text
@@ -132,6 +162,10 @@ def test_recorded_trace_layers(chip_trace):
     assert ctx.layer_s_per_step("quant_pack") == pytest.approx(114610e-9 / 2)
     assert ctx.layer_s_per_step("mix") == pytest.approx(82549e-9 / 2)
     assert ctx.layer_s_per_step("permute") is None
+    assert ctx.named_s_per_step("jit(qinf_quantize_pack)") == pytest.approx(
+        114610e-9 / 2)
+    assert ctx.named_s_per_step("jit(qinf_unpack_dequant_mix)") == \
+        pytest.approx(82549e-9 / 2)
     ops = dict(ctx.breakdown()["device_ops"])
     assert not any("container" in k for k in ops)
     assert any(k.startswith("qinf_quantize_pack_blocks") for k in ops)
