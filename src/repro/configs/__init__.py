@@ -1,6 +1,7 @@
 """Assigned architecture registry: ``get(arch_id)`` -> ModelConfig."""
-from repro.configs import (deepseek_moe_16b, llama_3_2_vision_90b,  # noqa: F401
-                           mixtral_8x7b, phi4_mini_3_8b, qwen2_7b, qwen3_1_7b,
+from repro.configs import (deepseek_moe_16b, deepseek_v2_lite,  # noqa: F401
+                           llama_3_2_vision_90b, mixtral_8x7b,
+                           phi4_mini_3_8b, qwen2_7b, qwen3_1_7b,
                            recurrentgemma_9b, rwkv6_7b, whisper_large_v3,
                            yi_9b)
 from repro.configs import shapes  # noqa: F401
@@ -11,6 +12,7 @@ _REGISTRY = {
     "mixtral-8x7b": mixtral_8x7b.config,
     "whisper-large-v3": whisper_large_v3.config,
     "deepseek-moe-16b": deepseek_moe_16b.config,
+    "deepseek-v2-lite": deepseek_v2_lite.config,
     "qwen3-1.7b": qwen3_1_7b.config,
     "recurrentgemma-9b": recurrentgemma_9b.config,
     "phi4-mini-3.8b": phi4_mini_3_8b.config,

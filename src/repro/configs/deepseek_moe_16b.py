@@ -2,8 +2,9 @@
 d_ff=1408 vocab=102400; fine-grained MoE: 2 shared + 64 routed top-6.
 [arXiv:2401.06066]
 
-Deviation noted in DESIGN.md: the real model's first layer is a dense FF;
-here every layer is MoE (uniform stack keeps the scan compact)."""
+Deviation: the published model's first layer has a dense FF; here every
+layer is MoE, on the capacity-dispatch path (deepseek-v2-lite has the
+leading dense stack and the dropless held-expert layer)."""
 from repro.models.transformer import ModelConfig
 
 

@@ -1,8 +1,10 @@
-"""Shared model primitives: norms, RoPE, attention (GQA / cross / sliding
-window / KV-cache), MLPs.  Pure functions over explicit param dicts."""
+"""Shared model primitives: norms, RoPE (plain and YaRN-scaled), attention
+(GQA / cross / sliding window / KV-cache), MLPs.  Pure functions over
+explicit param dicts."""
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -32,11 +34,53 @@ def layernorm(x, scale, bias, eps=1e-5):
 # RoPE
 # ---------------------------------------------------------------------------
 
-def rope_freqs(head_dim: int, theta: float, positions):
-    """positions (...,) -> (cos, sin) of shape (..., head_dim//2)."""
-    inv = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=F32) / head_dim))
+def rope_freqs(head_dim: int, theta: float, positions, inv=None,
+               scale: float = 1.0):
+    """positions (...,) -> (cos, sin) of shape (..., head_dim//2), from the
+    inverse frequencies ``inv`` (plain RoPE's by default), times ``scale``."""
+    if inv is None:
+        inv = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=F32)
+                               / head_dim))
     ang = positions.astype(F32)[..., None] * inv
-    return jnp.cos(ang), jnp.sin(ang)
+    return jnp.cos(ang) * scale, jnp.sin(ang) * scale
+
+
+def yarn_mscale(scale: float, mscale: float = 1.0) -> float:
+    """YaRN's attention factor as DeepSeek-V2 writes it: 0.1 mscale ln(s) + 1
+    for a context scale s > 1, else 1."""
+    return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, *, factor: float,
+                  original_max_position_embeddings: int, beta_fast: float,
+                  beta_slow: float, **_) -> np.ndarray:
+    """(dim//2,) float32 inverse frequencies of YaRN rope
+    (DeepseekV2YarnRotaryEmbedding): plain RoPE's ``theta^(-2i/dim)`` for the
+    fast dims, the same over ``factor`` for the slow ones, blended by a
+    linear ramp between the correction dims at which ``beta_fast`` and
+    ``beta_slow`` rotations fit in the original context."""
+    orig = original_max_position_embeddings
+
+    def correction_dim(rotations):
+        return (dim * math.log(orig / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0, 1)
+    extra = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float32) / dim)
+    inter = extra / factor
+    return (inter * ramp + extra * (1 - ramp)).astype(np.float32)
+
+
+def deinterleave(x):
+    """Rope pairs (2i, 2i+1) of the last dim -> rotate-half pairs
+    (i, i + hd/2), so that :func:`apply_rope` rotates DeepSeek's interleaved
+    pairs (q and k take the same permutation: their dot product holds)."""
+    return jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
 
 
 def apply_rope(x, cos, sin):
@@ -101,8 +145,9 @@ def attention(q, k, v, *, causal=True, window=None, q_offset=0,
     and query chunking (keeps the score tensor at chunk x S — the
     memory-sane formulation for 32k prefill).
 
-    q (B,T,H,hd); k, v (B,S,KV,hd).  ``kv_len``: dynamic number of valid KV
-    entries (decode with pre-allocated cache).  Returns (B,T,H,hd).
+    q (B,T,H,hd); k (B,S,KV,hd); v (B,S,KV,hd_v).  ``kv_len``: dynamic
+    number of valid KV entries (decode with pre-allocated cache).
+    ``softmax_scale`` defaults to hd^-1/2.  Returns (B,T,H,hd_v).
     """
     B, T, H, hd = q.shape
     S = k.shape[1]
@@ -132,7 +177,8 @@ def attention(q, k, v, *, causal=True, window=None, q_offset=0,
     # scan over query chunks: one (chunk x S) score tensor live at a time
     _, outs = jax.lax.scan(lambda c, xs: ((), block(xs[0], xs[1])),
                            (), (qs, offs))
-    return outs.transpose(1, 0, 2, 3, 4).reshape(B, T, H, hd)
+    # v's head dim, which latent attention sets apart from q's
+    return outs.transpose(1, 0, 2, 3, 4).reshape(B, T, H, -1)
 
 
 # ---------------------------------------------------------------------------

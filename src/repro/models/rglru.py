@@ -159,7 +159,7 @@ def forward(cfg, params, batch, *, mode="train", cache=None, pos=None):
     def one_rec(h, p_l, c_l):
         a, nc = rec_block(cfg, p_l, h, c_l, mode)
         h = h + a
-        m, _ = mlp_block(cfg, p_l, h)
+        m, _, _ = mlp_block(cfg, p_l, h)
         return h + m, nc
 
     def super_body(carry, xs):
@@ -183,7 +183,7 @@ def forward(cfg, params, batch, *, mode="train", cache=None, pos=None):
         a, nca = attn_block(cfg, pa, h, mode=mode, causal=True, rope=True,
                             window=cfg.local_window, cache=ca, pos=pos)
         h = h + a
-        m, _ = mlp_block(cfg, pa, h)
+        m, _, _ = mlp_block(cfg, pa, h)
         return h + m, (ncr, nca)
 
     xs = ((rec_super, params["attn_blocks"]) if cache is None

@@ -58,6 +58,11 @@ _RULES: Sequence[Tuple[str, Tuple]] = (
     (r"(wq|wk|wv|w_qkv|cross_wk|cross_wv)$", (None, None, "model")),
     (r"(wq|wk|wv)_b$", (None, "model")),            # qkv biases (L, H*hd)
     (r"wo$", (None, "model", None)),
+    # latent attention (L, D, kv_lora_rank + rope): one whole row, split
+    # into c_kv and the shared rope key; (L, kv_lora_rank, H*(nope + v)):
+    # heads
+    (r"wkv_a$", (None, None, None)),
+    (r"wkv_b$", (None, None, "model")),
     (r"wo_b$", (None, None)),
     # MLP, layer-stacked (L, D, F) / (L, F, D)
     (r"(w_gate|w_up|w_in)$", (None, None, "model")),

@@ -3,7 +3,10 @@
 Families
   dense  — llama-style decoder (GQA, RoPE, SwiGLU; qk_norm / qkv-bias / SWA
            variants cover qwen3, qwen2, phi4, yi)
-  moe    — dense skeleton with MoE FF layers (mixtral, deepseek-moe)
+  moe    — dense skeleton with MoE FF layers: capacity dispatch over every
+           expert (mixtral, deepseek-moe), or the dropless layer of a node
+           that holds a range of the routed experts, after a leading dense
+           stack and with latent attention (deepseek-v2-lite)
   vlm    — dense skeleton with gated cross-attention layers every k-th layer
            (llama-3.2-vision); vision embeddings arrive pre-projected (stub)
   encdec — whisper: encoder (full attn) + decoder (causal self + cross);
@@ -12,11 +15,22 @@ Families
   hybrid — recurrentgemma (repro.models.rglru)
 
 Layers are stacked on a leading L dim and executed with lax.scan so the HLO
-stays compact for 100-layer configs.  Everything is a pure function over an
-explicit param dict; init/abstract params share one template.
+stays compact for 100-layer configs; a decoder with ``first_k_dense``
+leading dense layers keeps them in a stack of their own (``dense_blocks``),
+scanned before ``blocks``.  Everything is a pure function over an explicit
+param dict; init/abstract params share one template.
+
+Latent attention (MLA, ``kv_lora_rank > 0``; DeepSeek-V2): q = x Wq per
+head, split into a ``hd - qk_rope_head_dim`` no-position part and a rope
+part; c = x Wkv_a, split into the latent ``c_kv`` and one rope key ``k_pe``
+shared by the heads; [k_nope, v] = RMSNorm(c_kv) Wkv_b per head; rope
+(YaRN-scaled where ``rope_scaling`` says so, on DeepSeek's interleaved
+pairs) on q_pe and k_pe; softmax scale hd^-1/2 times YaRN's mscale squared.
+The block runs under the named scope ``mla``.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 from typing import Any, Dict, Optional, Tuple
@@ -27,6 +41,7 @@ import numpy as np
 
 from repro.models import layers as L
 from repro.models import moe as moe_mod
+from repro.obs.trace import MLA
 
 F32 = jnp.float32
 
@@ -51,11 +66,27 @@ class ModelConfig:
     qk_norm: bool = False
     qkv_bias: bool = False
     sliding_window: Optional[int] = None
+    # latent attention (MLA) when kv_lora_rank > 0: head_dim is q/k's
+    # (no-position + rope parts), v_head_dim v's
+    kv_lora_rank: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN rope: {factor, original_max_position_embeddings, beta_fast,
+    # beta_slow, mscale, mscale_all_dim}, as the published config gives it
+    rope_scaling: Optional[Dict[str, float]] = None
+    first_k_dense: int = 0       # leading layers with a dense FF (d_ff)
     # moe
-    n_experts: int = 0
+    n_experts: int = 0           # routed experts the router scores
     n_shared_experts: int = 0
     top_k: int = 0
     capacity_factor: float = 1.25
+    moe_d_ff: int = 0            # expert width where d_ff is the dense one
+    # [first, stop) of the routed experts this node holds: the dropless
+    # held-expert layer; None: capacity dispatch over all of them
+    held_experts: Optional[Tuple[int, int]] = None
+    # weight of the model's own balance loss; None: the trainer's
+    # aux_weight
+    aux_loss_alpha: Optional[float] = None
     # vlm
     cross_attn_every: int = 0    # every k-th layer is cross-attention
     n_vision_tokens: int = 1601
@@ -85,6 +116,14 @@ class ModelConfig:
         return self.head_dim or self.d_model // self.n_heads
 
     @property
+    def expert_d_ff(self) -> int:
+        return self.moe_d_ff or self.d_ff
+
+    @property
+    def v_hd(self) -> int:
+        return self.v_head_dim or self.hd
+
+    @property
     def padded_vocab(self) -> int:
         return -(-self.vocab // 256) * 256
 
@@ -108,6 +147,15 @@ class ModelConfig:
             kw.update(n_experts=min(n_experts, self.n_experts),
                       top_k=min(self.top_k, 2),
                       n_shared_experts=min(self.n_shared_experts, 1))
+            if self.moe_d_ff:
+                kw.update(moe_d_ff=d_model)
+            if self.held_experts is not None:
+                kw.update(held_experts=(0, kw["n_experts"]))
+        if self.kv_lora_rank:
+            kw.update(kv_lora_rank=64, qk_rope_head_dim=16, v_head_dim=16,
+                      n_kv_heads=heads)
+        if self.first_k_dense:
+            kw.update(first_k_dense=1, n_layers=max(n_layers, 2))
         if self.family == "vlm":
             kw.update(cross_attn_every=2, n_vision_tokens=8)
         if self.family == "encdec":
@@ -157,7 +205,20 @@ def _iter_template(tpl, prefix=""):
         yield prefix, tpl
 
 
+def _mla_template(cfg: ModelConfig, Ls: int) -> Dict[str, ParamT]:
+    D, H, hd, R = cfg.d_model, cfg.n_heads, cfg.hd, cfg.kv_lora_rank
+    nope, vd = hd - cfg.qk_rope_head_dim, cfg.v_hd
+    return {"ln1": ParamT((Ls, D), "ones"),
+            "wq": ParamT((Ls, D, H * hd)),
+            "wkv_a": ParamT((Ls, D, R + cfg.qk_rope_head_dim)),
+            "kv_norm": ParamT((Ls, R), "ones"),
+            "wkv_b": ParamT((Ls, R, H * (nope + vd))),
+            "wo": ParamT((Ls, H * vd, D), fan=H * vd)}
+
+
 def _attn_template(cfg: ModelConfig, Ls: int, biases: bool) -> Dict[str, ParamT]:
+    if cfg.kv_lora_rank:
+        return _mla_template(cfg, Ls)
     D, H, KV, hd = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.hd
     t: Dict[str, ParamT] = {
         "ln1": ParamT((Ls, D), "ones"),
@@ -191,12 +252,15 @@ def _mlp_template(cfg: ModelConfig, Ls: int, gelu: bool) -> Dict[str, ParamT]:
 
 
 def _moe_template(cfg: ModelConfig, Ls: int) -> Dict[str, ParamT]:
-    D, F, E = cfg.d_model, cfg.d_ff, cfg.n_experts
+    """Router over all ``n_experts``; expert weights of the held ones."""
+    D, F, E = cfg.d_model, cfg.expert_d_ff, cfg.n_experts
+    Eh = (E if cfg.held_experts is None
+          else cfg.held_experts[1] - cfg.held_experts[0])
     t = {"ln2": ParamT((Ls, D), "ones"),
          "router": ParamT((Ls, D, E)),
-         "experts_gate": ParamT((Ls, E, D, F)),
-         "experts_up": ParamT((Ls, E, D, F)),
-         "experts_down": ParamT((Ls, E, F, D), fan=F)}
+         "experts_gate": ParamT((Ls, Eh, D, F)),
+         "experts_up": ParamT((Ls, Eh, D, F)),
+         "experts_down": ParamT((Ls, Eh, F, D), fan=F)}
     if cfg.n_shared_experts:
         Fs = F * cfg.n_shared_experts
         t.update({"shared_gate": ParamT((Ls, D, Fs)),
@@ -227,8 +291,13 @@ def param_template(cfg: ModelConfig):
         blk.update(_mlp_template(cfg, cfg.n_layers, gelu=cfg.act == "gelu"))
         tpl["blocks"] = blk
     elif cfg.family == "moe":
-        blk = _attn_template(cfg, cfg.n_layers, biases=False)
-        blk.update(_moe_template(cfg, cfg.n_layers))
+        n_dense = cfg.first_k_dense
+        if n_dense:
+            blk = _attn_template(cfg, n_dense, biases=False)
+            blk.update(_mlp_template(cfg, n_dense, gelu=False))
+            tpl["dense_blocks"] = blk
+        blk = _attn_template(cfg, cfg.n_layers - n_dense, biases=False)
+        blk.update(_moe_template(cfg, cfg.n_layers - n_dense))
         tpl["blocks"] = blk
     elif cfg.family == "vlm":
         k = cfg.cross_attn_every
@@ -305,6 +374,53 @@ def _proj(x, w, b=None):
     return out
 
 
+def _positions(mode, B, T, pos):
+    """Rope positions: (B, 1) at ``pos`` in decode, else 0..T-1."""
+    return jnp.full((B, 1), pos) if mode == "decode" else jnp.arange(T)
+
+
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """hd^-1/2, times YaRN's mscale squared where rope is YaRN-scaled."""
+    scale = cfg.hd ** -0.5
+    rs = cfg.rope_scaling
+    if rs and rs.get("mscale_all_dim"):
+        scale *= L.yarn_mscale(rs["factor"], rs["mscale_all_dim"]) ** 2
+    return scale
+
+
+def mla_rope(cfg: ModelConfig, positions):
+    """(cos, sin) of the rope part of a latent-attention head: YaRN
+    frequencies and cos/sin scale where ``rope_scaling`` says so."""
+    d, rs = cfg.qk_rope_head_dim, cfg.rope_scaling
+    if not rs:
+        return L.rope_freqs(d, cfg.rope_theta, positions)
+    inv = jnp.asarray(L.yarn_inv_freq(d, cfg.rope_theta, **rs))
+    scale = (L.yarn_mscale(rs["factor"], rs.get("mscale", 1.0))
+             / L.yarn_mscale(rs["factor"], rs.get("mscale_all_dim", 0.0)))
+    return L.rope_freqs(d, cfg.rope_theta, positions, inv=inv, scale=scale)
+
+
+def mla_qkv(cfg: ModelConfig, g, xn, positions):
+    """Latent attention's q, k (B, T, H, hd) and v (B, T, H, v_hd) from the
+    normed input (module docstring); ``g(name)`` reads the block's params."""
+    B, T, _ = xn.shape
+    H, hd, R, dr = cfg.n_heads, cfg.hd, cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    nope = hd - dr
+    q = _proj(xn, g("wq")).reshape(B, T, H, hd)
+    c = _proj(xn, g("wkv_a"))
+    c_kv, k_pe = c[..., :R], c[..., R:].reshape(B, T, 1, dr)
+    kv = _proj(L.rmsnorm(c_kv, g("kv_norm")), g("wkv_b")).reshape(
+        B, T, H, nope + cfg.v_hd)
+    k_nope, v = kv[..., :nope], kv[..., nope:]
+    cos, sin = mla_rope(cfg, positions)
+    q_pe = L.apply_rope(L.deinterleave(q[..., nope:]), cos, sin)
+    k_pe = L.apply_rope(L.deinterleave(k_pe), cos, sin)
+    q = jnp.concatenate([q[..., :nope], q_pe], axis=-1)
+    k = jnp.concatenate([k_nope, jnp.broadcast_to(k_pe, (B, T, H, dr))],
+                        axis=-1)
+    return q, k, v
+
+
 def attn_block(cfg: ModelConfig, p, x, *, mode: str, causal=True, rope=True,
                window=None, cache=None, pos=None, kv_src=None, cross=False,
                prefix=""):
@@ -315,15 +431,33 @@ def attn_block(cfg: ModelConfig, p, x, *, mode: str, causal=True, rope=True,
     ring-buffer cache at ``pos % cache_len`` (sliding-window archs have
     cache_len == window) and masks with kv_len — causality follows because
     the query's absolute position dominates every cached entry.
+    Latent attention (``cfg.kv_lora_rank``) runs the same cache path with
+    its own q, k, v (:func:`mla_qkv`) under the named scope ``mla``.
     Returns (attn_out, new_cache)."""
+    if cfg.kv_lora_rank and cross:
+        raise ValueError("latent attention is self-attention only")
+    with (jax.named_scope(MLA) if cfg.kv_lora_rank
+          else contextlib.nullcontext()):
+        return _attn_block(cfg, p, x, mode=mode, causal=causal, rope=rope,
+                           window=window, cache=cache, pos=pos,
+                           kv_src=kv_src, cross=cross, prefix=prefix)
+
+
+def _attn_block(cfg: ModelConfig, p, x, *, mode: str, causal=True, rope=True,
+                window=None, cache=None, pos=None, kv_src=None, cross=False,
+                prefix=""):
     g = lambda name: p.get(prefix + name)
     B, T, D = x.shape
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
+    mla = bool(cfg.kv_lora_rank)
 
     xn = _norm(cfg, x, g("ln1"), g("ln1_b"))
-    q = _proj(xn, g("wq"), g("wq_b")).reshape(B, T, H, hd)
-    if g("q_norm") is not None:
-        q = L.rmsnorm(q, g("q_norm"))
+    if mla:
+        q, k, v = mla_qkv(cfg, g, xn, _positions(mode, B, T, pos))
+    else:
+        q = _proj(xn, g("wq"), g("wq_b")).reshape(B, T, H, hd)
+        if g("q_norm") is not None:
+            q = L.rmsnorm(q, g("q_norm"))
 
     new_cache = cache
     kv_len = None
@@ -343,16 +477,14 @@ def attn_block(cfg: ModelConfig, p, x, *, mode: str, causal=True, rope=True,
             if mode == "prefill":
                 new_cache = {"k": k.astype(cfg.dtype), "v": v.astype(cfg.dtype)}
     else:
-        k = _proj(xn, g("wk"), g("wk_b")).reshape(B, T, KV, hd)
-        v = _proj(xn, g("wv"), g("wv_b")).reshape(B, T, KV, hd)
-        if g("k_norm") is not None:
-            k = L.rmsnorm(k, g("k_norm"))
-        if rope:
-            if mode == "decode":
-                cos, sin = L.rope_freqs(hd, cfg.rope_theta,
-                                        jnp.full((B, 1), pos))
-            else:
-                cos, sin = L.rope_freqs(hd, cfg.rope_theta, jnp.arange(T))
+        if not mla:
+            k = _proj(xn, g("wk"), g("wk_b")).reshape(B, T, KV, hd)
+            v = _proj(xn, g("wv"), g("wv_b")).reshape(B, T, KV, hd)
+            if g("k_norm") is not None:
+                k = L.rmsnorm(k, g("k_norm"))
+        if rope and not mla:
+            cos, sin = L.rope_freqs(hd, cfg.rope_theta,
+                                    _positions(mode, B, T, pos))
             q = L.apply_rope(q, cos, sin)
             k = L.apply_rope(k, cos, sin)
         if mode == "decode":
@@ -374,8 +506,9 @@ def attn_block(cfg: ModelConfig, p, x, *, mode: str, causal=True, rope=True,
             new_cache = {"k": ck, "v": cv}
 
     out = L.attention(q, k, v, causal=causal_eff, window=window,
-                      q_offset=q_off, kv_len=kv_len)
-    out = out.reshape(B, T, H * hd)
+                      q_offset=q_off, kv_len=kv_len,
+                      softmax_scale=mla_softmax_scale(cfg) if mla else None)
+    out = out.reshape(B, T, H * cfg.v_hd)
     out = jnp.einsum("btk,kd->btd", out, g("wo").astype(x.dtype))
     if g("wo_b") is not None:
         out = out + g("wo_b").astype(x.dtype)
@@ -383,21 +516,29 @@ def attn_block(cfg: ModelConfig, p, x, *, mode: str, causal=True, rope=True,
 
 
 def mlp_block(cfg: ModelConfig, p, x, prefix=""):
+    """The FF sub-block (pre-norm, residual applied by the caller).
+    Returns (out, aux loss, load): ``load`` is the held-expert layer's
+    (held pairs, max load) (moe.held_moe_mlp), else None."""
     g = lambda name: p.get(prefix + name)
     xn = _norm(cfg, x, g("ln2"), g("ln2_b"))
     if cfg.family == "moe" and g("router") is not None:
         shared = None
         if cfg.n_shared_experts:
             shared = (g("shared_gate"), g("shared_up"), g("shared_down"))
+        experts = (g("router"), g("experts_gate"), g("experts_up"),
+                   g("experts_down"))
+        if cfg.held_experts is not None:
+            return moe_mod.held_moe_mlp(
+                xn, *experts, top_k=cfg.top_k, held=cfg.held_experts,
+                shared=shared)
         out, aux = moe_mod.moe_mlp(
-            xn, g("router"), g("experts_gate"), g("experts_up"),
-            g("experts_down"), top_k=cfg.top_k,
+            xn, *experts, top_k=cfg.top_k,
             capacity_factor=cfg.capacity_factor, shared=shared)
-        return out, aux
+        return out, aux, None
     if g("w_in") is not None:
         return L.gelu_mlp(xn, g("w_in"), g("w_in_b"), g("w_out"),
-                          g("w_out_b")), 0.0
-    return L.swiglu(xn, g("w_gate"), g("w_up"), g("w_down")), 0.0
+                          g("w_out_b")), 0.0, None
+    return L.swiglu(xn, g("w_gate"), g("w_up"), g("w_down")), 0.0, None
 
 
 # ---------------------------------------------------------------------------
@@ -406,7 +547,8 @@ def mlp_block(cfg: ModelConfig, p, x, prefix=""):
 
 def run_stack(cfg, stack, x, *, mode, causal=True, window=None, cache=None,
               pos=None):
-    """lax.scan over the layer-stacked self-attention params (and cache)."""
+    """lax.scan over the layer-stacked self-attention params (and cache).
+    Returns (x, new cache, summed aux loss, per-layer load or None)."""
     use_rope = cfg.norm != "layernorm"  # whisper (layernorm) has no RoPE
 
     def body(carry, xs):
@@ -418,12 +560,13 @@ def run_stack(cfg, stack, x, *, mode, causal=True, window=None, cache=None,
         a, nc = attn_block(cfg, p_l, h, mode=mode, causal=causal,
                            rope=use_rope, window=window, cache=c_l, pos=pos)
         h = h + a
-        m, aux = mlp_block(cfg, p_l, h)
-        return (h + m, aux_sum + aux), nc
+        m, aux, load = mlp_block(cfg, p_l, h)
+        return (h + m, aux_sum + aux), (nc, load)
 
     xs = stack if cache is None else (stack, cache)
-    (x, aux), new_cache = jax.lax.scan(body, (x, jnp.float32(0.0)), xs)
-    return x, new_cache, aux
+    (x, aux), (new_cache, load) = jax.lax.scan(
+        body, (x, jnp.float32(0.0)), xs)
+    return x, new_cache, aux, load
 
 
 # ---------------------------------------------------------------------------
@@ -442,6 +585,23 @@ def lm_logits(cfg, params, x):
 def forward(cfg: ModelConfig, params, batch, *, mode="train", cache=None,
             pos=None):
     """Family dispatch.  Returns (logits, new_cache, aux_loss)."""
+    return forward_with_load(cfg, params, batch, mode=mode, cache=cache,
+                             pos=pos)[:3]
+
+
+def forward_with_load(cfg: ModelConfig, params, batch, *, mode="train",
+                      cache=None, pos=None):
+    """:func:`forward` and the expert load of a held-expert stack:
+    (logits, new_cache, aux_loss, load), ``load`` a pair of (layers,)
+    arrays (held pairs, max load) or None."""
+    if cfg.family not in ("dense", "moe"):
+        return _forward_other(cfg, params, batch, mode=mode, cache=cache,
+                              pos=pos) + (None,)
+    return _forward_decoder(cfg, params, batch, mode=mode, cache=cache,
+                            pos=pos)
+
+
+def _forward_other(cfg, params, batch, *, mode, cache, pos):
     if cfg.family == "ssm":
         from repro.models import rwkv6
         return rwkv6.forward(cfg, params, batch, mode=mode, cache=cache,
@@ -453,21 +613,26 @@ def forward(cfg: ModelConfig, params, batch, *, mode="train", cache=None,
     if cfg.family == "encdec":
         return _forward_encdec(cfg, params, batch, mode=mode, cache=cache,
                                pos=pos)
-    if cfg.family == "vlm":
-        return _forward_vlm(cfg, params, batch, mode=mode, cache=cache,
-                            pos=pos)
-    return _forward_decoder(cfg, params, batch, mode=mode, cache=cache,
-                            pos=pos)
+    return _forward_vlm(cfg, params, batch, mode=mode, cache=cache, pos=pos)
 
 
 def _forward_decoder(cfg, params, batch, *, mode, cache, pos):
+    """The decoder stacks in order: the leading dense one, where the model
+    has it, then ``blocks``."""
     x = embed_tokens(cfg, params, batch["tokens"])
-    x, new_cache, aux = run_stack(
-        cfg, params["blocks"], x, mode=mode, causal=True,
-        window=cfg.sliding_window, cache=None if cache is None
-        else cache["blocks"], pos=pos)
+    aux, load, new_cache = jnp.float32(0.0), None, {}
+    for name in ("dense_blocks", "blocks"):
+        if name not in params:
+            continue
+        x, new_cache[name], a, ld = run_stack(
+            cfg, params[name], x, mode=mode, causal=True,
+            window=cfg.sliding_window, cache=None if cache is None
+            else cache[name], pos=pos)
+        aux = aux + a
+        if ld is not None:
+            load = ld
     logits = lm_logits(cfg, params, x)
-    return logits, (None if new_cache is None else {"blocks": new_cache}), aux
+    return logits, (None if cache is None else new_cache), aux, load
 
 
 def _forward_vlm(cfg, params, batch, *, mode, cache, pos):
@@ -488,13 +653,13 @@ def _forward_vlm(cfg, params, batch, *, mode, cache, pos):
         else:
             (ps, px), (cs, cx) = xs
         # (k-1) self layers
-        h, cs_new, aux = run_stack(cfg, ps, h, mode=mode, causal=True,
-                                   cache=cs, pos=pos)
+        h, cs_new, aux, _ = run_stack(cfg, ps, h, mode=mode, causal=True,
+                                      cache=cs, pos=pos)
         # 1 gated cross layer
         a, cx_new = attn_block(cfg, px, h, mode=mode, rope=False, cache=cx,
                                pos=pos, kv_src=vision, cross=True)
         h = h + jnp.tanh(px["gate_attn"]).astype(h.dtype) * a
-        m, aux2 = mlp_block(cfg, px, h)
+        m, aux2, _ = mlp_block(cfg, px, h)
         h = h + jnp.tanh(px["gate_mlp"]).astype(h.dtype) * m
         return (h, aux_sum + aux + aux2), (cs_new, cx_new)
 
@@ -522,8 +687,8 @@ def _forward_encdec(cfg, params, batch, *, mode, cache, pos):
         frames = batch["frames"].astype(cfg.dtype)  # (B, S_enc, D) stub
         pe = L.sinusoidal_pos(frames.shape[1], cfg.d_model).astype(cfg.dtype)
         h = frames + pe[None]
-        h, _, _ = run_stack(cfg, params["enc_blocks"], h, mode="train",
-                            causal=False)
+        h, _, _, _ = run_stack(cfg, params["enc_blocks"], h, mode="train",
+                               causal=False)
         enc_out = _norm(cfg, h, params["enc_final_norm"],
                         params.get("enc_final_norm_b"))
 
@@ -550,7 +715,7 @@ def _forward_encdec(cfg, params, batch, *, mode, cache, pos):
                                   cache=c_cross, pos=pos, kv_src=enc_out,
                                   cross=True, prefix="x_")
         h = h + xa
-        m, aux = mlp_block(cfg, p_l, h)
+        m, aux, _ = mlp_block(cfg, p_l, h)
         return (h + m, aux_sum + aux), (nc_self, nc_cross)
 
     xs = (params["dec_blocks"] if cache is None
@@ -571,7 +736,7 @@ def init_cache(cfg: ModelConfig, B: int, S: int, abstract=False):
     """Pre-allocated decode cache for seq_len S."""
     mk = (lambda shape: jax.ShapeDtypeStruct(shape, cfg.dtype)) if abstract \
         else (lambda shape: jnp.zeros(shape, cfg.dtype))
-    KV, hd = cfg.n_kv_heads, cfg.hd
+    KV, hd, vd = cfg.n_kv_heads, cfg.hd, cfg.v_hd
     if cfg.family == "ssm":
         from repro.models import rwkv6
         return rwkv6.init_cache(cfg, B, mk)
@@ -581,9 +746,13 @@ def init_cache(cfg: ModelConfig, B: int, S: int, abstract=False):
     Seff = S if cfg.sliding_window is None else min(S, cfg.sliding_window)
     if cfg.decode_cache_cap is not None:
         Seff = min(Seff, cfg.decode_cache_cap)
-    kv = lambda n, s: {"k": mk((n, B, s, KV, hd)), "v": mk((n, B, s, KV, hd))}
+    kv = lambda n, s: {"k": mk((n, B, s, KV, hd)), "v": mk((n, B, s, KV, vd))}
     if cfg.family == "dense" or cfg.family == "moe":
-        return {"blocks": kv(cfg.n_layers, Seff)}
+        n_dense = cfg.first_k_dense
+        cache = {"blocks": kv(cfg.n_layers - n_dense, Seff)}
+        if n_dense:
+            cache["dense_blocks"] = kv(n_dense, Seff)
+        return cache
     if cfg.family == "vlm":
         n_cross = cfg.n_layers // cfg.cross_attn_every
         return {"self": kv(cfg.n_layers - n_cross, Seff),

@@ -14,8 +14,10 @@ Three layers (ISSUE 6 / ROADMAP "Roofline-gated perf CI"):
   time.
 
 * names in the profiler's trace (:mod:`repro.obs.trace`) — the named
-  scopes of the train step's parts (:data:`SCOPES`), which every device
-  op carries in its HLO ``op_name``, and the host spans of
+  scopes of the train step's parts (:data:`SCOPES`) and of the model's
+  own parts under ``fwd_bwd`` (:data:`MLA`, :data:`MOE_ROUTE`,
+  :data:`MOE_EXPERTS`), which every device op carries in its HLO
+  ``op_name``, and the host spans of
   ``TrainerRunner.step`` (:data:`STEP` and its children), written through
   :func:`annotate` on the profiler's clock.  ``bench/`` reads both.
 
@@ -26,14 +28,16 @@ Three layers (ISSUE 6 / ROADMAP "Roofline-gated perf CI"):
 """
 from repro.obs.meters import Meters, current_meters, env_info, using_meters
 from repro.obs.report import RunReport, build_report, wire_breakdown
-from repro.obs.trace import (CONSENSUS, EXCHANGE, FWD_BWD, NOISE, SCOPES,
-                             STEP, STEP_CALL, STEP_PLACE, UPDATE,
-                             WIRE_LAYOUT, annotate, span)
+from repro.obs.trace import (CONSENSUS, EXCHANGE, FWD_BWD, MLA,
+                             MOE_EXPERTS, MOE_ROUTE, NOISE, SCOPES, STEP,
+                             STEP_CALL, STEP_PLACE, UPDATE, WIRE_LAYOUT,
+                             annotate, span)
 
 __all__ = [
     "Meters", "current_meters", "using_meters", "env_info",
     "span", "annotate",
     "FWD_BWD", "NOISE", "WIRE_LAYOUT", "EXCHANGE", "UPDATE", "CONSENSUS",
-    "SCOPES", "STEP", "STEP_PLACE", "STEP_CALL",
+    "SCOPES", "MLA", "MOE_ROUTE", "MOE_EXPERTS",
+    "STEP", "STEP_PLACE", "STEP_CALL",
     "RunReport", "build_report", "wire_breakdown",
 ]

@@ -29,7 +29,12 @@ The names of the train step's parts on the device live here too: the
 step wraps each part in ``jax.named_scope(<scope>)``, so every op the
 part makes carries the scope in its HLO ``op_name`` and a profile can
 attribute the op.  Scopes are metadata: the compiled program is the same.
-No scope nests inside another, so an op lies under at most one.
+No scope of :data:`SCOPES` nests inside another of them, so an op lies
+under at most one.  The model's own parts (:data:`MLA`, :data:`MOE_ROUTE`,
+:data:`MOE_EXPERTS`: latent attention, the expert layer's routing and its
+grouped matmuls) nest under ``fwd_bwd``, in the forward and in its
+transpose alike; a reader of one takes the innermost scope an op lies
+under.
 """
 from __future__ import annotations
 
@@ -78,6 +83,12 @@ EXCHANGE = "prox_lead/exchange"        # ppermutes; the identity path's mix
 UPDATE = "prox_lead/update"            # Prox-LEAD lines 6-10, elementwise
 CONSENSUS = "consensus"                # the consensus metric
 SCOPES = (FWD_BWD, NOISE, WIRE_LAYOUT, EXCHANGE, UPDATE, CONSENSUS)
+
+# Named scopes of the model's parts, under FWD_BWD (see module docstring).
+MLA = "mla"                            # a latent-attention block
+MOE_ROUTE = "moe/route"                # router, top-k, balance loss,
+#                                        grouping and combine
+MOE_EXPERTS = "moe/experts"            # the held experts' grouped matmuls
 
 # Host spans of TrainerRunner.step.
 STEP = "repro.step"

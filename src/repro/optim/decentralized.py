@@ -103,7 +103,8 @@ class TrainerConfig:
     scales_bf16: bool = False       # §Perf iteration 3
     shard_aligned_blocks: bool = False  # §Perf iteration 4: block | shard
     tp_ways: int = 16               # model-axis width (for block alignment)
-    aux_weight: float = 0.01        # MoE load-balance weight
+    aux_weight: float = 0.01        # MoE load-balance weight, where the
+    #                               # model states none (aux_loss_alpha)
     # beyond-paper: precondition the gradient estimate per node before the
     # Prox-LEAD update (Adam second-moment normalization).  The algorithm
     # sees a preconditioned oracle; compression/gossip are unchanged.
@@ -281,22 +282,28 @@ class DecentralizedTrainer:
 
     # ------------------------------------------------------------------ loss
     def _node_loss(self, params, batch_node):
-        logits, _, aux = TR.forward(self.mcfg, params, batch_node)
+        logits, _, aux, load = TR.forward_with_load(self.mcfg, params,
+                                                    batch_node)
         ce = TR.loss_fn(self.mcfg, logits, batch_node["labels"])
-        return ce + self.tcfg.aux_weight * aux, ce
+        w = (self.tcfg.aux_weight if self.mcfg.aux_loss_alpha is None
+             else self.mcfg.aux_loss_alpha)
+        return ce + w * aux, (ce, load)
 
     def loss_and_grad(self, X, batch):
+        """(mean CE over nodes, gradient, expert load): the load of a
+        held-expert model is a pair of (N, layers) arrays (held pairs, max
+        load), else None."""
         def total(Xs):
-            losses, ces = jax.vmap(self._node_loss)(Xs, batch)
-            return jnp.sum(losses), jnp.mean(ces)
+            losses, (ces, load) = jax.vmap(self._node_loss)(Xs, batch)
+            return jnp.sum(losses), (jnp.mean(ces), load)
 
         with jax.named_scope(obs.FWD_BWD):
-            (tot, ce), G = jax.value_and_grad(total, has_aux=True)(X)
-        return ce, G
+            (tot, (ce, load)), G = jax.value_and_grad(total, has_aux=True)(X)
+        return ce, G, load
 
     # ------------------------------------------------------------------ step
     def train_step(self, state: TrainState, batch) -> Tuple[TrainState, dict]:
-        ce, G = self.loss_and_grad(state.plead.X, batch)
+        ce, G, load = self.loss_and_grad(state.plead.X, batch)
         precond = state.precond
         if self.tcfg.precondition == "adam":
             with jax.named_scope(obs.UPDATE):
@@ -317,6 +324,11 @@ class DecentralizedTrainer:
             step = state.step + 1
         metrics = {"loss": ce, "consensus": consensus,
                    "step": state.step}
+        if load is not None:
+            # pairs routed to held experts, over layers and nodes; the most
+            # loaded held expert over the mean, the worst layer and node
+            metrics["moe_held_pairs"] = jnp.sum(load[0])
+            metrics["moe_max_load"] = jnp.max(load[1])
         return TrainState(plead, step, precond), metrics
 
     def _adam_precondition(self, G, precond, step):

@@ -42,6 +42,7 @@ def test_full_config_exact(arch_setup):
         "mixtral-8x7b": (32, 4096, 32, 8, 14336, 32000),
         "whisper-large-v3": (32, 1280, 20, 20, 5120, 51866),
         "deepseek-moe-16b": (28, 2048, 16, 16, 1408, 102400),
+        "deepseek-v2-lite": (27, 2048, 16, 16, 10944, 102400),
         "qwen3-1.7b": (28, 2048, 16, 8, 6144, 151936),
         "recurrentgemma-9b": (38, 4096, 16, 1, 12288, 256000),
         "phi4-mini-3.8b": (32, 3072, 24, 8, 8192, 200064),
